@@ -14,6 +14,7 @@ new device count.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 from typing import Callable, Optional
 
@@ -30,6 +31,17 @@ def _flatten(tree):
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
         out.append((key, leaf))
     return out, treedef
+
+
+def tree_digest(tree) -> str:
+    """sha256 over every leaf's key, dtype, shape and bytes: two trees with
+    equal digests are bitwise equal."""
+    h = hashlib.sha256()
+    for key, leaf in _flatten(tree)[0]:
+        a = np.ascontiguousarray(leaf)
+        h.update(f"{key}:{a.dtype.str}:{a.shape};".encode())
+        h.update(a.reshape(-1).view(np.uint8))
+    return h.hexdigest()
 
 
 class CheckpointManager:
@@ -68,22 +80,17 @@ class CheckpointManager:
         return {"step": step, **info}
 
     def finalize(self) -> None:
-        """Close deferred checkpoint files (waits for their drain)."""
-        for fd in self._deferred_fds:
-            try:
-                self.fs.close(fd)
-            except Exception:
-                pass
-        self._deferred_fds.clear()
+        """Close deferred checkpoint files (waits for their drain).  A close
+        error (e.g. a drain barrier that timed out) propagates; the files
+        not yet closed stay deferred."""
+        while self._deferred_fds:
+            self.fs.close(self._deferred_fds.pop(0))
 
     def close(self) -> None:
         self.finalize()
         if self._manifest_fd is not None:
-            try:
-                self.fs.close(self._manifest_fd)
-            except Exception:
-                pass
-            self._manifest_fd = None
+            fd, self._manifest_fd = self._manifest_fd, None
+            self.fs.close(fd)
 
     # --------------------------------------------------------------- restore
     def latest_step(self) -> Optional[int]:
@@ -118,13 +125,11 @@ class CheckpointManager:
         return self._manifest_fd
 
     def _read_manifest(self) -> dict:
-        try:
-            fd = self._mfd()
-            size = self.fs.size(fd)
-            raw = self.fs.pread(fd, size, 0) if size else b""
-            return json.loads(raw) if raw else {}
-        except Exception:
-            return {}
+        """{} only for a missing or empty manifest; a corrupt manifest or a
+        failed read raises rather than silently restarting from step 0."""
+        fd = self._mfd()
+        size = self.fs.size(fd)
+        return json.loads(self.fs.pread(fd, size, 0)) if size else {}
 
     def _write_manifest(self, manifest: dict) -> None:
         blob = json.dumps(manifest).encode()

@@ -46,7 +46,9 @@ class SyntheticTokens:
         return {"seed": self.seed, "step": self.step}
 
     def load_state(self, state: dict) -> None:
-        assert state["seed"] == self.seed, "corpus seed mismatch"
+        if state["seed"] != self.seed:
+            raise ValueError(f"corpus seed mismatch: saved {state['seed']}, "
+                             f"this pipeline {self.seed}")
         self.step = state["step"]
 
     def save_state(self, fs, path: str = "/datapipe.json") -> None:
@@ -56,16 +58,16 @@ class SyntheticTokens:
         fs.close(fd)
 
     def restore_state(self, fs, path: str = "/datapipe.json") -> bool:
-        try:
-            fd = fs.open(path)
-            raw = fs.pread(fd, 256, 0)
-            fs.close(fd)
-            if not raw.strip():
-                return False
-            self.load_state(json.loads(raw.decode()))
-            return True
-        except Exception:
+        """False for a missing or empty state file; a corrupt one, or one
+        saved under another seed, raises rather than silently replaying the
+        corpus from step 0 under a resumed model."""
+        fd = fs.open(path)
+        raw = fs.pread(fd, 256, 0)
+        fs.close(fd)
+        if not raw.strip():
             return False
+        self.load_state(json.loads(raw.decode()))
+        return True
 
 
 class FileBackedTokens:

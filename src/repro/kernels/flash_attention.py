@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 
@@ -111,26 +112,13 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
         out_specs=pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, n_q * blk_q, Dv), q.dtype),
         scratch_shapes=[
-            _vmem((blk_q, 1)),
-            _vmem((blk_q, 1)),
-            _vmem((blk_q, Dv)),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, Dv), jnp.float32),
         ],
-        compiler_params=_tpu_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
     out = out[:, :Sq].reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)
     return out
-
-
-def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, jnp.float32)
-
-
-def _tpu_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        return None

@@ -15,12 +15,27 @@ import jax.numpy as jnp
 
 from repro.configs.registry import all_archs, get_config, get_smoke
 from repro.core import NVCache, Policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build
 from repro.storage.fsapi import NVCacheFS
 from repro.storage.tiers import BLOB, Tier
 
+JOURNAL = "/requests.jsonl"
 
-def main(argv=None):
+
+def open_fs() -> NVCacheFS:
+    """An NVCache-backed file system over a fresh blob tier."""
+    return NVCacheFS(NVCache(Policy(entry_size=4096, log_entries=4096,
+                                    read_cache_pages=64, batch_min=8,
+                                    batch_max=256, verify_crc=False),
+                             Tier(BLOB)))
+
+
+def main(argv=None, fs=None):
+    """Serve one batch and return the summary it prints.  ``fs``: journal
+    requests (to ``JOURNAL``) on this file system and leave it open; by
+    default a fresh :func:`open_fs`, shut down at the end."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=all_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -33,11 +48,10 @@ def main(argv=None):
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))
 
-    nv = NVCache(Policy(entry_size=4096, log_entries=4096,
-                        read_cache_pages=64, batch_min=8, batch_max=256,
-                        verify_crc=False), Tier(BLOB))
-    fs = NVCacheFS(nv)
-    log_fd = fs.open("/requests.jsonl")
+    own_fs = fs is None
+    if own_fs:
+        fs = open_fs()
+    log_fd = fs.open(JOURNAL)
     log_off = 0
 
     B, P = args.batch, args.prompt_len
@@ -64,14 +78,19 @@ def main(argv=None):
     jax.block_until_ready(logits)
     dt = time.perf_counter() - t0
     tokens = jnp.concatenate(out, 1)
-    line = (json.dumps({"completed": tokens.shape[0] * tokens.shape[1],
-                        "seconds": dt}) + "\n").encode()
+    completed = tokens.shape[0] * tokens.shape[1]
+    line = (json.dumps({"completed": completed, "seconds": dt}) + "\n").encode()
     fs.pwrite(log_fd, line, log_off)
-    print(json.dumps({"arch": cfg.arch, "batch": B,
-                      "tokens_per_s": B * args.tokens / dt,
-                      "sample": tokens[0, :8].tolist()}))
     fs.close(log_fd)
-    nv.shutdown()
+    summary = {"arch": cfg.arch, "batch": B, "prompt_len": P,
+               "completed": completed,
+               # wall clock of prefill + decode, compilation included
+               "tokens_per_s": completed / dt,
+               "sample": tokens[0, :8].tolist()}
+    print(json.dumps(summary))
+    if own_fs:
+        fs.nv.shutdown()
+    return summary
 
 
 if __name__ == "__main__":

@@ -87,11 +87,8 @@ def _moe_shard_map(cfg: ModelConfig, p, x, mesh, M):
     and back.  Wire bytes per device ≈ 2·T_loc·k·cf·d — two orders of
     magnitude below what the auto-partitioned scatter/gather produced for
     arctic-480b (the baseline's dominant roofline term)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:      # jax<0.7 spelling
-        from jax.experimental.shard_map import shard_map
 
     B, S, d = x.shape
     E, K = cfg.n_experts, cfg.top_k
@@ -130,14 +127,10 @@ def _moe_shard_map(cfg: ModelConfig, p, x, mesh, M):
         return y.reshape(Bl, Sl, d), aux
 
     xspec = P(dp if B % max(1, _prod(mesh, dp)) == 0 else None, "model", None)
-    kwargs = dict(mesh=mesh,
+    f = shard_map(local, mesh=mesh,
                   in_specs=(xspec, P(), P("model", None, None),
                             P("model", None, None), P("model", None, None)),
-                  out_specs=(xspec, P()))
-    try:
-        f = shard_map(local, check_vma=False, **kwargs)
-    except TypeError:
-        f = shard_map(local, check_rep=False, **kwargs)
+                  out_specs=(xspec, P()), check_vma=False)
     out, aux = f(x, p["router"], p["wg"], p["wu"], p["wd"])
     return out, aux
 

@@ -17,11 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def pipeline_apply(mesh, axis: str, stage_fn, stage_params, microbatches):
@@ -59,11 +55,8 @@ def pipeline_apply(mesh, axis: str, stage_fn, stage_params, microbatches):
 
     P = jax.sharding.PartitionSpec
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    kwargs = dict(mesh=mesh, in_specs=(pspec, P()), out_specs=P())
-    try:
-        f = _shard_map(local, check_vma=False, **kwargs)
-    except TypeError:
-        f = _shard_map(local, check_rep=False, **kwargs)
+    f = _shard_map(local, mesh=mesh, in_specs=(pspec, P()), out_specs=P(),
+                   check_vma=False)
     return f(stage_params, microbatches)
 
 

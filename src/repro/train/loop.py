@@ -9,6 +9,7 @@ manifest -> restore -> resume the data pipeline at the exact step.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import time
 from typing import Callable, Optional
@@ -47,47 +48,55 @@ def train(model: Model, optimizer: AdamW, pipeline, fs, *,
           mesh=None, fsdp: bool = True, seed: int = 0,
           heartbeat: Optional[Callable[[int], None]] = None,
           compress_grads: bool = False):
-    """Returns (final_state, history list of metric dicts)."""
+    """Returns (final_state, history list of metric dicts).  Each history
+    entry carries its ``step``; a step that checkpointed also carries
+    ``save_s`` (device-to-host copy, encode and durable write)."""
     mgr = CheckpointManager(fs, keep=keep)
     metrics_log = MetricsLog(fs)
     step_fn = tsteps.make_train_step(model, optimizer, compress=compress_grads)
 
+    latest = mgr.latest_step()
+    start = 0 if latest is None else latest
+    if latest is not None:
+        pipeline.restore_state(fs)
+    # the first batch also gives the mesh its batch shardings
+    first = pipeline.next()
     if mesh is not None:
-        spec_like = jax.eval_shape(lambda: pipeline.next())
-        (in_sh, b_sh), (out_sh, _), _ = tsteps.train_shardings(
-            model, optimizer, mesh, spec_like, fsdp=fsdp)
+        (in_sh, b_sh), (out_sh, _), like = tsteps.train_shardings(
+            model, optimizer, mesh, first, fsdp=fsdp)
         step_fn = jax.jit(tsteps.bind_mesh(step_fn, mesh),
                           in_shardings=(in_sh, b_sh),
                           out_shardings=(out_sh, None), donate_argnums=(0,))
     else:
+        in_sh = None
+        like = tsteps.abstract_train_state(model, optimizer)
         step_fn = jax.jit(step_fn, donate_argnums=(0,))
 
-    # ---- restore or init ---------------------------------------------------
-    state = tsteps.init_train_state(model, optimizer, jax.random.PRNGKey(seed))
-    start = 0
-    latest = mgr.latest_step()
-    if latest is not None:
-        abstract = jax.tree.map(np.asarray, state)
-        state = jax.tree.map(
-            lambda like, a: a.astype(like.dtype),
-            abstract, mgr.restore(abstract, step=latest))
-        state = jax.tree.map(jax.numpy.asarray, state)
-        pipeline.restore_state(fs)
-        start = latest
+    # ---- restore or init (placed straight onto the mesh's shardings) -------
+    if latest is None:
+        state = jax.jit(lambda key: tsteps.init_train_state(model, optimizer, key),
+                        out_shardings=in_sh)(jax.random.PRNGKey(seed))
+    else:
+        host = jax.tree.map(lambda l, a: a.astype(l.dtype),
+                            like, mgr.restore(like, step=latest))
+        state = jax.device_put(host, in_sh)
+        del host        # the host copy would otherwise live through training
     history = []
 
-    for step in range(start, total_steps):
-        batch = pipeline.next()
+    batches = itertools.chain([first], iter(pipeline.next, None))
+    for step, batch in zip(range(start, total_steps), batches):
         t0 = time.perf_counter()
         state, metrics = step_fn(state, batch)
         jax.block_until_ready(metrics["loss"])
         metrics = dict(metrics, step_time=time.perf_counter() - t0)
         metrics_log.log(step, metrics)
-        history.append({k: float(v) for k, v in metrics.items()})
+        history.append({"step": step, **{k: float(v) for k, v in metrics.items()}})
         if heartbeat:
             heartbeat(step)
         if (step + 1) % ckpt_every == 0 or step + 1 == total_steps:
+            t0 = time.perf_counter()
             host_state = jax.tree.map(np.asarray, state)
             mgr.save(step + 1, host_state)
             pipeline.save_state(fs)
+            history[-1]["save_s"] = time.perf_counter() - t0
     return state, history

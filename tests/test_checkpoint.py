@@ -140,6 +140,26 @@ def test_zlib_fallback_roundtrip():
         codec.zstandard = real
 
 
+def test_missing_manifest_reads_as_no_checkpoint():
+    mgr = CheckpointManager(TierFS(Tier(DRAM)))
+    assert mgr.latest_step() is None
+    with pytest.raises(FileNotFoundError):
+        mgr.restore(_tree())
+
+
+def test_corrupt_manifest_raises():
+    """A torn or garbled manifest must not read as 'no checkpoint' (which
+    would silently restart training from step 0)."""
+    fs = TierFS(Tier(DRAM))
+    mgr = CheckpointManager(fs)
+    mgr.save(1, _tree())
+    fd = fs.open("/ckpt/MANIFEST.json")
+    fs.pwrite(fd, b"{\"steps\": [1], \"lat", 0)
+    fs.ftruncate(fd, 19)
+    with pytest.raises(ValueError):
+        CheckpointManager(fs).latest_step()
+
+
 def test_gc_keeps_last_k():
     fs = TierFS(Tier(DRAM))
     mgr = CheckpointManager(fs, keep=2)

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.data.pipeline import FileBackedTokens, SyntheticTokens
 from repro.optim import grad_compress
@@ -31,6 +32,22 @@ def test_pipeline_state_through_fs():
     q = SyntheticTokens(1000, 2, 16, seed=1)
     assert q.restore_state(fs)
     np.testing.assert_array_equal(p.next()["tokens"], q.next()["tokens"])
+
+
+def test_pipeline_state_missing_reads_as_fresh():
+    assert not SyntheticTokens(1000, 2, 16, seed=1).restore_state(TierFS(Tier(DRAM)))
+
+
+@pytest.mark.parametrize("blob", [b"{\"seed\": 1, \"st", b"{\"seed\": 2, \"step\": 3}"],
+                         ids=["corrupt", "other_seed"])
+def test_pipeline_state_bad_file_raises(blob):
+    """A resumed model must not silently get the corpus from step 0."""
+    fs = TierFS(Tier(DRAM))
+    fd = fs.open("/datapipe.json")
+    fs.pwrite(fd, blob.ljust(256), 0)
+    fs.close(fd)
+    with pytest.raises(ValueError):
+        SyntheticTokens(1000, 2, 16, seed=1).restore_state(fs)
 
 
 def test_file_backed_tokens():

@@ -64,6 +64,21 @@ def test_crash_restart_resumes_exactly():
     nv2.shutdown()
 
 
+def test_mesh_path_trains_like_single_device():
+    """The sharded path (here a 1x1 mesh) sees the same batches from step 0
+    and computes the same losses as the plain jit."""
+    from repro.launch.mesh import make_single_mesh
+    losses = []
+    for mesh in (None, make_single_mesh()):
+        _tier, nv, model, opt, pipe = _setup()
+        _, hist = train_loop.train(model, opt, pipe, NVCacheFS(nv),
+                                   total_steps=3, ckpt_every=3, mesh=mesh)
+        losses.append([h["loss"] for h in hist])
+        assert pipe.step == 3
+        nv.shutdown()
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+
+
 def test_table1_property_matrix():
     """Paper Table I, as executable assertions."""
     # NVCache: synchronous durability (write durable before return) and
