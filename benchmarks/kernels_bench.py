@@ -2,7 +2,7 @@
 interpret-mode parity check per kernel, plus the NVMM log commit-path
 micro-kernel at K ∈ {1, 4} shards (the storage hot path is as much a
 "kernel" of this system as the jax ops).  Real TPU timings are out of scope
-for this container; the roofline table covers the compiled-path analysis."""
+here: the chip benchmark is ``bench/run.py``."""
 from __future__ import annotations
 
 import threading

@@ -13,7 +13,6 @@ device-time calibration keeps the paper's cross-stack ratios meaningful).
         machine-readable via benchmarks/run_all.py -> BENCH_pr2.json)
   ckpt  checkpoint-path booster comparison                (beyond paper)
   kern  kernel micro-bench + oracle parity                (framework)
-  roofline  per-(arch x shape) terms from dry-run HLO     (see EXPERIMENTS.md)
 """
 from __future__ import annotations
 
@@ -52,10 +51,6 @@ def main() -> None:
     if "kern" in which:
         from benchmarks import kernels_bench
         kernels_bench.run()
-    if "roofline" in which:
-        from benchmarks import roofline
-        rows = roofline.table()
-        print(roofline.fmt_table(rows))
 
 
 if __name__ == "__main__":

@@ -34,8 +34,9 @@ Checks (source of truth for the hierarchy is the LOCK HIERARCHY table in
   state the race detector cannot see.  Annotation completeness — the
   guarded-by table's version of the hierarchy-table L001 rule.
 * ``L006`` — every metric/span name literal (arguments to the
-  ``repro.obs.metrics`` constructors / ``Registry`` binders, keys of a
-  ``bind_group`` dict, keys of a ``_LEVELS`` span table) must match the
+  ``repro.obs.metrics`` constructors / ``Registry`` binders, to
+  ``repro.obs.span``, keys of a ``bind_group``
+  dict, keys of a ``_LEVELS`` span table) must match the
   documented ``subsystem.noun_unit`` grammar (see
   ``src/repro/obs/README.md``); the registry enforces the same rule at
   runtime, this catches names on paths tests never execute.
@@ -60,7 +61,7 @@ _IO_CALLS = {"pwrite", "pwritev", "pread", "preadv", "fsync"}
 #: call names whose first string-literal argument is a metric/span name
 _METRIC_CTORS = {"Counter", "Gauge", "Histogram", "BoundGauge",
                  "counter", "gauge", "histogram", "bind", "bind_summary",
-                 "merged_snapshot"}
+                 "merged_snapshot", "span"}
 
 
 class Finding:

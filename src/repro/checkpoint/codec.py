@@ -29,6 +29,8 @@ from typing import Optional
 import msgpack
 import numpy as np
 
+from repro import obs
+
 try:
     import zstandard
 except ImportError:                       # optional dependency (see docstring)
@@ -93,7 +95,8 @@ class Writer:
         self._w(MAGIC)
 
     def _w(self, data: bytes):
-        self.fs.pwrite(self.fd, data, self.off)
+        with obs.span("ckpt.write_us", bytes=len(data)):
+            self.fs.pwrite(self.fd, data, self.off)
         self.off += len(data)
 
     def put_leaf(self, path: str, arr) -> None:
@@ -111,27 +114,30 @@ class Writer:
             self._put_chunk(path, a, start, end, part)
 
     def _put_chunk(self, path, a, start, end, part):
-        raw = np.ascontiguousarray(part)
-        meta = {"p": path, "dt": str(a.dtype), "gs": list(a.shape),
-                "s": start, "e": end, "enc": self.encoding}
-        if self.encoding == ENC_INT8 and raw.dtype.kind == "f" and raw.size >= 256:
-            q, scale, pad = _quant_np(raw.view(raw.dtype))
-            payload, used_zlib = _compress(q.tobytes() + scale.tobytes())
-            meta["pad"] = pad
-            meta["nsc"] = scale.size
-            if used_zlib:
-                meta["zc"] = 1          # int8 payload compressed with zlib
-        elif self.encoding in (ENC_ZSTD, ENC_ZLIB):
-            # ENC_ZLIB is an explicit request for the portable codec — honour
-            # it even when zstandard is installed
-            payload, used_zlib = _compress(raw.tobytes(),
-                                           force_zlib=self.encoding == ENC_ZLIB)
-            meta["enc"] = ENC_ZLIB if used_zlib else ENC_ZSTD
-        else:
-            meta["enc"] = ENC_RAW
-            payload = raw.tobytes()
-        hdr = msgpack.packb(meta)
-        rec = struct.pack("<II", len(hdr), len(payload)) + hdr + payload
+        # one record's encode: contiguous copy, compression, framing
+        with obs.span("ckpt.encode_us", bytes=part.nbytes) as sp:
+            raw = np.ascontiguousarray(part)
+            meta = {"p": path, "dt": str(a.dtype), "gs": list(a.shape),
+                    "s": start, "e": end, "enc": self.encoding}
+            if self.encoding == ENC_INT8 and raw.dtype.kind == "f" and raw.size >= 256:
+                q, scale, pad = _quant_np(raw.view(raw.dtype))
+                payload, used_zlib = _compress(q.tobytes() + scale.tobytes())
+                meta["pad"] = pad
+                meta["nsc"] = scale.size
+                if used_zlib:
+                    meta["zc"] = 1          # int8 payload compressed with zlib
+            elif self.encoding in (ENC_ZSTD, ENC_ZLIB):
+                # ENC_ZLIB is an explicit request for the portable codec — honour
+                # it even when zstandard is installed
+                payload, used_zlib = _compress(raw.tobytes(),
+                                               force_zlib=self.encoding == ENC_ZLIB)
+                meta["enc"] = ENC_ZLIB if used_zlib else ENC_ZSTD
+            else:
+                meta["enc"] = ENC_RAW
+                payload = raw.tobytes()
+            hdr = msgpack.packb(meta)
+            sp.set(out_bytes=len(payload))
+            rec = struct.pack("<II", len(hdr), len(payload)) + hdr + payload
         self.index.append((path, int(start), int(end), self.off, len(rec)))
         self._w(rec)
 
@@ -172,11 +178,14 @@ class Reader:
         for _p, start, end, off, ln in entries:
             if rows is not None and (end <= rows[0] or start >= rows[1]):
                 continue
-            rec = self.fs.pread(self.fd, ln, off)
-            hlen, plen = struct.unpack("<II", rec[:8])
-            meta = msgpack.unpackb(rec[8:8 + hlen])
-            payload = rec[8 + hlen:8 + hlen + plen]
-            arr = self._decode(meta, payload, start, end)
+            with obs.span("ckpt.read_us", bytes=ln):
+                rec = self.fs.pread(self.fd, ln, off)
+            with obs.span("ckpt.decode_us") as sp:
+                hlen, plen = struct.unpack("<II", rec[:8])
+                meta = msgpack.unpackb(rec[8:8 + hlen])
+                payload = rec[8 + hlen:8 + hlen + plen]
+                arr = self._decode(meta, payload, start, end)
+                sp.set(bytes=arr.nbytes)
             if rows is not None:
                 lo = max(rows[0], start) - start
                 hi = min(rows[1], end) - start

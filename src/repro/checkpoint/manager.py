@@ -21,6 +21,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import codec
 
 
@@ -57,34 +58,38 @@ class CheckpointManager:
 
     # ------------------------------------------------------------------ save
     def save(self, step: int, tree) -> dict:
-        # finalize previous steps' files now (their background drain has had
-        # a full checkpoint interval to complete — close() barely blocks)
-        self.finalize()
-        path = f"{self.dir}/step_{step:08d}.ckpt"
-        w = codec.Writer(self.fs, path, encoding=self.encoding,
-                         close_on_finish=False)
-        flat, _ = _flatten(tree)
-        for key, leaf in flat:
-            w.put_leaf(key, leaf)
-        info = w.finish()
-        self._deferred_fds.append(w.fd)
-        manifest = self._read_manifest()
-        manifest["steps"] = sorted(set(manifest.get("steps", []) + [step]))
-        manifest["latest"] = max(manifest["steps"])
-        manifest["files"] = {**manifest.get("files", {}),
-                             str(step): {"path": path, **info}}
-        self._gc(manifest)
-        # the manifest write commits the checkpoint (crash before it ->
-        # previous step restores; the data file is garbage-collected)
-        self._write_manifest(manifest)
+        with obs.span("ckpt.save_us", step=step) as sp:
+            # finalize previous steps' files now (their background drain has
+            # had a full checkpoint interval to complete — close() barely
+            # blocks)
+            self.finalize()
+            path = f"{self.dir}/step_{step:08d}.ckpt"
+            w = codec.Writer(self.fs, path, encoding=self.encoding,
+                             close_on_finish=False)
+            flat, _ = _flatten(tree)
+            sp.set(bytes=sum(getattr(leaf, "nbytes", 0) for _, leaf in flat))
+            for key, leaf in flat:
+                w.put_leaf(key, leaf)
+            info = w.finish()
+            self._deferred_fds.append(w.fd)
+            manifest = self._read_manifest()
+            manifest["steps"] = sorted(set(manifest.get("steps", []) + [step]))
+            manifest["latest"] = max(manifest["steps"])
+            manifest["files"] = {**manifest.get("files", {}),
+                                 str(step): {"path": path, **info}}
+            self._gc(manifest)
+            # the manifest write commits the checkpoint (crash before it ->
+            # previous step restores; the data file is garbage-collected)
+            self._write_manifest(manifest)
         return {"step": step, **info}
 
     def finalize(self) -> None:
         """Close deferred checkpoint files (waits for their drain).  A close
         error (e.g. a drain barrier that timed out) propagates; the files
         not yet closed stay deferred."""
-        while self._deferred_fds:
-            self.fs.close(self._deferred_fds.pop(0))
+        with obs.span("ckpt.finalize_us"):
+            while self._deferred_fds:
+                self.fs.close(self._deferred_fds.pop(0))
 
     def close(self) -> None:
         self.finalize()
@@ -108,14 +113,16 @@ class CheckpointManager:
         if step is None:
             raise FileNotFoundError("no checkpoint")
         path = m["files"][str(step)]["path"]
-        r = codec.Reader(self.fs, path)
-        flat, treedef = _flatten(tree_like)
-        leaves = []
-        for key, like in flat:
-            rows = slice_rows(key, tuple(np.shape(like))) if slice_rows else None
-            arr = r.read_leaf(key, rows=rows)
-            leaves.append(arr)
-        r.close()
+        with obs.span("ckpt.restore_us", step=step) as sp:
+            r = codec.Reader(self.fs, path)
+            flat, treedef = _flatten(tree_like)
+            leaves = []
+            for key, like in flat:
+                rows = slice_rows(key, tuple(np.shape(like))) if slice_rows else None
+                arr = r.read_leaf(key, rows=rows)
+                leaves.append(arr)
+            r.close()
+            sp.set(bytes=sum(a.nbytes for a in leaves))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     # ------------------------------------------------------------- internals
@@ -132,11 +139,12 @@ class CheckpointManager:
         return json.loads(self.fs.pread(fd, size, 0)) if size else {}
 
     def _write_manifest(self, manifest: dict) -> None:
-        blob = json.dumps(manifest).encode()
-        fd = self._mfd()
-        # single pwrite -> one atomic committed group in NVCache
-        self.fs.pwrite(fd, blob.ljust(max(self.fs.size(fd), len(blob)), b" "), 0)
-        self.fs.fsync(fd)
+        with obs.span("ckpt.manifest_us"):
+            blob = json.dumps(manifest).encode()
+            fd = self._mfd()
+            # single pwrite -> one atomic committed group in NVCache
+            self.fs.pwrite(fd, blob.ljust(max(self.fs.size(fd), len(blob)), b" "), 0)
+            self.fs.fsync(fd)
 
     def _gc(self, manifest: dict) -> None:
         steps = manifest.get("steps", [])

@@ -56,6 +56,7 @@ from repro.core import locking
 from repro.core.drain import FsyncEpochScheduler
 from repro.core.log import CG_HEAD, META_FDID, LogShard, NVLog
 from repro.obs import flight as _obs_flight
+from repro.obs import spans as obs_spans
 
 
 class CleanupThread(threading.Thread):
@@ -201,49 +202,52 @@ class CleanupThread(threading.Thread):
         if eff == 0:                          # whole batch stays open
             self._note_deferred(start, run)
             return
-        obs = self.obs
-        lv2 = obs is not None and obs.prof.lv2
-        # phase 1: group by (file, page), materialize images, coalesce extents
-        t0 = time.perf_counter_ns() if lv2 else 0
-        plan = _drain.build_plan(shard, start, eff, self.resolve_file, pol,
-                                 abort=self._abort)
-        if lv2:
-            obs.prof.h_drain_plan.record_ns(time.perf_counter_ns() - t0)
-        if plan is None:
-            return
-        # phase 2: extent writes under page cleanup locks + index retire
-        t0 = time.perf_counter_ns() if lv2 else 0
-        drained = _drain.apply_plan(plan, pol, abort=self._abort, stats=self)
-        if lv2:
-            obs.prof.h_drain_apply.record_ns(time.perf_counter_ns() - t0)
-        if drained is None:
-            return
-        if self._abort(_drain.FSYNC):
-            return
-        t0 = time.perf_counter_ns() if lv2 else 0
-        for f in drained:
-            if getattr(f, "unlinked", False):
-                continue    # anonymous (unlinked-while-open) file: its
-                #             bytes die with the name on any crash, so
-                #             device durability buys nothing — this skip is
-                #             what makes deleting a hot journal cheap
-            if getattr(f, "skip_drain_fsync", False):
-                continue    # ftruncate(0) WAL-reset window: the journaled
-                #             truncate (already committed, higher seq) will
-                #             discard these bytes on any crash — same
-                #             reasoning as the unlinked skip, scoped to the
-                #             barrier the truncate itself runs
+        # one timeline span per batch: plan, apply, fsync and consume
+        with obs_spans.span("drain.batch_us", entries=eff) as sp:
+            obs = self.obs
+            lv2 = obs is not None and obs.prof.lv2
+            # phase 1: group by (file, page), materialize images, coalesce extents
+            t0 = time.perf_counter_ns() if lv2 else 0
+            plan = _drain.build_plan(shard, start, eff, self.resolve_file, pol,
+                                     abort=self._abort)
+            if lv2:
+                obs.prof.h_drain_plan.record_ns(time.perf_counter_ns() - t0)
+            if plan is None:
+                return
+            sp.set(bytes=sum(fp.nbytes for fp in plan.files))
+            # phase 2: extent writes under page cleanup locks + index retire
+            t0 = time.perf_counter_ns() if lv2 else 0
+            drained = _drain.apply_plan(plan, pol, abort=self._abort, stats=self)
+            if lv2:
+                obs.prof.h_drain_apply.record_ns(time.perf_counter_ns() - t0)
+            if drained is None:
+                return
+            if self._abort(_drain.FSYNC):
+                return
+            t0 = time.perf_counter_ns() if lv2 else 0
+            for f in drained:
+                if getattr(f, "unlinked", False):
+                    continue    # anonymous (unlinked-while-open) file: its
+                    #             bytes die with the name on any crash, so
+                    #             device durability buys nothing — this skip is
+                    #             what makes deleting a hot journal cheap
+                if getattr(f, "skip_drain_fsync", False):
+                    continue    # ftruncate(0) WAL-reset window: the journaled
+                    #             truncate (already committed, higher seq) will
+                    #             discard these bytes on any crash — same
+                    #             reasoning as the unlinked skip, scoped to the
+                    #             barrier the truncate itself runs
 
-            self.stats_fsyncs += 1            # one request per file per batch
-            if self.fsync_scheduler is not None:
-                self.fsync_scheduler.fsync(f.backend)
-            else:
-                f.backend.fsync()
-        if lv2:
-            obs.prof.h_drain_fsync.record_ns(time.perf_counter_ns() - t0)
-        if self._abort(_drain.CONSUME):
-            return
-        shard.consume(start, eff)             # durably retire the batch
+                self.stats_fsyncs += 1            # one request per file per batch
+                if self.fsync_scheduler is not None:
+                    self.fsync_scheduler.fsync(f.backend)
+                else:
+                    f.backend.fsync()
+            if lv2:
+                obs.prof.h_drain_fsync.record_ns(time.perf_counter_ns() - t0)
+            if self._abort(_drain.CONSUME):
+                return
+            shard.consume(start, eff)             # durably retire the batch
         if obs is not None and obs.flight is not None:
             obs.flight.record(_obs_flight.EV_BATCH, shard.sid, start, eff)
         if self.meta_gate is not None and plan.meta_entries:

@@ -78,6 +78,7 @@ from repro.core.nvmm import NVMM
 from repro.core.policy import Policy, SUPERBLOCK
 from repro.obs import flight as obs_flight
 from repro.obs import metrics
+from repro.obs import spans as obs_spans
 
 MAGIC = 0x4E56_4341_4348_4532  # "NVCACHE2" (v1 was the unsharded layout)
 VERSION = 5                    # v3 added the persisted route table region;
@@ -319,15 +320,18 @@ class LogShard:
         waited_ns = 0
         try:
             with self._space:
-                while self.head + k - self.volatile_tail > self.n:
-                    remaining = None
-                    if deadline is not None:
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            raise LogFullTimeout(f"shard {self.sid} full")
-                    t0 = time.monotonic_ns()
-                    self._space.wait(timeout=remaining)
-                    waited_ns += time.monotonic_ns() - t0
+                if self.head + k - self.volatile_tail > self.n:
+                    # one timeline span per blocking episode
+                    with obs_spans.span("log.alloc_wait_us", shard=self.sid):
+                        while self.head + k - self.volatile_tail > self.n:
+                            remaining = None
+                            if deadline is not None:
+                                remaining = deadline - time.monotonic()
+                                if remaining <= 0:
+                                    raise LogFullTimeout(f"shard {self.sid} full")
+                            t0 = time.monotonic_ns()
+                            self._space.wait(timeout=remaining)
+                            waited_ns += time.monotonic_ns() - t0
                 idx = self.head
                 self.head += k
                 self.stats_appended += k

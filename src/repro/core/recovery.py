@@ -50,6 +50,7 @@ from repro.core.log import (CG_HEAD, META_FDID, META_NO_FDID, MOP_CREATE,
 from repro.core.nvmm import NVMM
 from repro.core.policy import Policy
 from repro.core.router import load_route_record
+from repro.obs import spans as obs_spans
 
 
 @dataclasses.dataclass
@@ -92,6 +93,13 @@ def recover(nvmm: NVMM, policy: Policy,
     tier through ``__self__``, and a region with no namespace records
     never needs more than ``open``.
     """
+    with obs_spans.span("nv.recover_us") as sp:
+        stats = _recover(nvmm, policy, backend)
+        sp.set(entries=stats.entries_replayed)
+    return stats
+
+
+def _recover(nvmm: NVMM, policy: Policy, backend) -> RecoveryStats:
     if hasattr(backend, "open"):
         tier, open_backend = backend, backend.open
     else:

@@ -94,12 +94,13 @@ def _block(cfg: ModelConfig, pl, x, rope, window, *, return_kv=False):
             out, kv = out
         return x + out, aux, kv
 
-    h = rmsnorm(x, pl["norm1"], cfg.norm_eps)
-    if cfg.attn_kind == "mla":
-        a = attn.mla_forward(cfg, pl["attn"], h, rope, return_kv=return_kv)
-    else:
-        a = attn.gqa_forward(cfg, pl["attn"], h, rope, window=window,
-                             return_kv=return_kv)
+    with jax.named_scope("attention"):
+        h = rmsnorm(x, pl["norm1"], cfg.norm_eps)
+        if cfg.attn_kind == "mla":
+            a = attn.mla_forward(cfg, pl["attn"], h, rope, return_kv=return_kv)
+        else:
+            a = attn.gqa_forward(cfg, pl["attn"], h, rope, window=window,
+                                 return_kv=return_kv)
     if return_kv:
         a, kv = a
     if cfg.family == "hybrid":
@@ -111,19 +112,19 @@ def _block(cfg: ModelConfig, pl, x, rope, window, *, return_kv=False):
     else:
         x = x + a
 
-    h2 = rmsnorm(x, pl["norm2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        m, aux = moe_mod.moe_forward(cfg, pl["moe"], h2)
-        if cfg.dense_residual:
-            m = m + swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
-                           pl["mlp"]["wu"].astype(x.dtype),
-                           pl["mlp"]["wd"].astype(x.dtype))
-        x = x + m
-    else:
-        x = x + swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
+    with jax.named_scope("mlp"):
+        h2 = rmsnorm(x, pl["norm2"], cfg.norm_eps)
+        if cfg.family == "moe":
+            m, aux = moe_mod.moe_forward(cfg, pl["moe"], h2)
+            if cfg.dense_residual:
+                m = m + swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
+                               pl["mlp"]["wu"].astype(x.dtype),
+                               pl["mlp"]["wd"].astype(x.dtype))
+        else:
+            m = swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
                        pl["mlp"]["wu"].astype(x.dtype),
                        pl["mlp"]["wd"].astype(x.dtype))
-    return x, aux, kv
+    return x + m, aux, kv
 
 
 def _remat(cfg, fn):
@@ -137,8 +138,8 @@ def _remat(cfg, fn):
 # ----------------------------------------------------------------- forward
 
 def _embed(cfg, params, tokens):
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.cdt)
-    return x
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"], tokens, axis=0).astype(cfg.cdt)
 
 
 def _rope_for(cfg: ModelConfig, positions):
@@ -170,9 +171,10 @@ def forward(cfg: ModelConfig, params, tokens, positions=None):
         return y, aux
 
     x, auxs = jax.lax.scan(_remat(cfg, body), x, (params["layers"], windows))
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    un = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
-    logits = (x @ un.astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("loss"):       # the head exists for the loss
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        un = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
+        logits = (x @ un.astype(x.dtype)).astype(jnp.float32)
     return logits, jnp.sum(auxs)
 
 
@@ -180,12 +182,13 @@ def loss_fn(cfg: ModelConfig, params, batch, *, aux_weight=0.01):
     """Next-token cross-entropy.  batch: {tokens: (B,S)}."""
     tokens = batch["tokens"]
     logits, aux = forward(cfg, params, tokens, batch.get("positions"))
-    tgt = tokens[:, 1:]
-    lg = logits[:, :-1]
-    lse = jax.nn.logsumexp(lg, axis=-1)
-    ll = jnp.take_along_axis(lg, tgt[..., None], axis=-1)[..., 0]
-    loss = jnp.mean(lse - ll)
-    return loss + aux_weight * aux, {"ce": loss, "aux": aux}
+    with jax.named_scope("loss"):
+        tgt = tokens[:, 1:]
+        lg = logits[:, :-1]
+        lse = jax.nn.logsumexp(lg, axis=-1)
+        ll = jnp.take_along_axis(lg, tgt[..., None], axis=-1)[..., 0]
+        loss = jnp.mean(lse - ll)
+        return loss + aux_weight * aux, {"ce": loss, "aux": aux}
 
 
 # ------------------------------------------------------------------ serving

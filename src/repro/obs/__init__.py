@@ -7,7 +7,10 @@ construction and threaded through the log shards and the drain pool:
   behind per-thread shards merged on read; no hot-path locks.
 * :mod:`repro.obs.spans` — timed spans over the write pipeline, the
   read-miss path and the drain/barrier stalls, gated by
-  ``Policy.obs_level`` so level 0 costs a branch per op.
+  ``Policy.obs_level`` so level 0 costs a branch per op; and
+  :func:`span`, the timeline spans of the train loop, the checkpoint
+  codec and the engine's cold paths, which reach the JAX profiler's
+  trace while a session records.
 * :mod:`repro.obs.flight` — a CRC'd ring of fixed-size event records
   carved into the NVMM layout (VERSION 5): the engine's black box,
   decoded into a forensic timeline by ``core/recovery.py`` after a
@@ -21,7 +24,7 @@ from __future__ import annotations
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import (BoundGauge, Counter, Gauge, Histogram,
                                Registry)
-from repro.obs.spans import SpanProfiler
+from repro.obs.spans import SpanProfiler, span
 
 
 class ObsPlane:
@@ -45,4 +48,4 @@ class ObsPlane:
 
 
 __all__ = ["ObsPlane", "Registry", "Counter", "Gauge", "Histogram",
-           "BoundGauge", "SpanProfiler", "FlightRecorder"]
+           "BoundGauge", "SpanProfiler", "FlightRecorder", "span"]

@@ -9,7 +9,6 @@ manifest -> restore -> resume the data pipeline at the exact step.
 """
 from __future__ import annotations
 
-import itertools
 import json
 import time
 from typing import Callable, Optional
@@ -17,6 +16,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.models.registry import Model
 from repro.optim.adamw import AdamW
@@ -32,15 +32,21 @@ class MetricsLog:
         self.off = fs.size(self.fd)
 
     def log(self, step: int, metrics: dict) -> None:
-        rec = {"step": step}
-        for k, v in metrics.items():
-            try:
-                rec[k] = float(v)
-            except (TypeError, ValueError):
-                pass
-        line = (json.dumps(rec) + "\n").encode()
-        self.fs.pwrite(self.fd, line, self.off)
-        self.off += len(line)
+        with obs.span("train.metrics_us") as sp:
+            rec = {"step": step}
+            for k, v in metrics.items():
+                try:
+                    rec[k] = float(v)
+                except (TypeError, ValueError):
+                    pass
+            line = (json.dumps(rec) + "\n").encode()
+            sp.set(bytes=len(line))
+            self.fs.pwrite(self.fd, line, self.off)
+            self.off += len(line)
+
+
+def _nbytes(tree) -> int:
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 def train(model: Model, optimizer: AdamW, pipeline, fs, *,
@@ -50,7 +56,11 @@ def train(model: Model, optimizer: AdamW, pipeline, fs, *,
           compress_grads: bool = False):
     """Returns (final_state, history list of metric dicts).  Each history
     entry carries its ``step``; a step that checkpointed also carries
-    ``save_s`` (device-to-host copy, encode and durable write)."""
+    ``save_s`` (device-to-host copy, encode and durable write).
+
+    The loop's phases are timeline spans (``repro.obs.span``): the step,
+    the batch pull, the metrics line, the save and its device-to-host
+    copy, the restore and its host-to-device put, and the pipeline state."""
     mgr = CheckpointManager(fs, keep=keep)
     metrics_log = MetricsLog(fs)
     step_fn = tsteps.make_train_step(model, optimizer, compress=compress_grads)
@@ -58,12 +68,14 @@ def train(model: Model, optimizer: AdamW, pipeline, fs, *,
     latest = mgr.latest_step()
     start = 0 if latest is None else latest
     if latest is not None:
-        pipeline.restore_state(fs)
+        with obs.span("train.pipeline_us"):
+            pipeline.restore_state(fs)
     # the first batch also gives the mesh its batch shardings
-    first = pipeline.next()
+    with obs.span("train.batch_us"):
+        batch = pipeline.next()
     if mesh is not None:
         (in_sh, b_sh), (out_sh, _), like = tsteps.train_shardings(
-            model, optimizer, mesh, first, fsdp=fsdp)
+            model, optimizer, mesh, batch, fsdp=fsdp)
         step_fn = jax.jit(tsteps.bind_mesh(step_fn, mesh),
                           in_shardings=(in_sh, b_sh),
                           out_shardings=(out_sh, None), donate_argnums=(0,))
@@ -77,26 +89,37 @@ def train(model: Model, optimizer: AdamW, pipeline, fs, *,
         state = jax.jit(lambda key: tsteps.init_train_state(model, optimizer, key),
                         out_shardings=in_sh)(jax.random.PRNGKey(seed))
     else:
-        host = jax.tree.map(lambda l, a: a.astype(l.dtype),
-                            like, mgr.restore(like, step=latest))
-        state = jax.device_put(host, in_sh)
-        del host        # the host copy would otherwise live through training
+        with obs.span("train.restore_us", step=latest):
+            host = mgr.restore(like, step=latest)
+            with obs.span("train.h2d_us", bytes=_nbytes(host)):
+                state = jax.device_put(
+                    jax.tree.map(lambda l, a: a.astype(l.dtype), like, host), in_sh)
+            del host    # the host copy would otherwise live through training
     history = []
 
-    batches = itertools.chain([first], iter(pipeline.next, None))
-    for step, batch in zip(range(start, total_steps), batches):
-        t0 = time.perf_counter()
-        state, metrics = step_fn(state, batch)
-        jax.block_until_ready(metrics["loss"])
-        metrics = dict(metrics, step_time=time.perf_counter() - t0)
+    # no batch is pulled past total_steps; a None from the feed ends the loop
+    for step in range(start, total_steps):
+        if step > start:
+            with obs.span("train.batch_us"):
+                batch = pipeline.next()
+        if batch is None:
+            break
+        with obs.span("train.step_us", step=step):
+            t0 = time.perf_counter()
+            state, metrics = step_fn(state, batch)
+            jax.block_until_ready(metrics["loss"])
+            metrics = dict(metrics, step_time=time.perf_counter() - t0)
         metrics_log.log(step, metrics)
         history.append({"step": step, **{k: float(v) for k, v in metrics.items()}})
         if heartbeat:
             heartbeat(step)
         if (step + 1) % ckpt_every == 0 or step + 1 == total_steps:
-            t0 = time.perf_counter()
-            host_state = jax.tree.map(np.asarray, state)
-            mgr.save(step + 1, host_state)
-            pipeline.save_state(fs)
-            history[-1]["save_s"] = time.perf_counter() - t0
+            with obs.span("train.save_us", step=step + 1):
+                t0 = time.perf_counter()
+                with obs.span("train.d2h_us", bytes=_nbytes(state)):
+                    host_state = jax.tree.map(np.asarray, state)
+                mgr.save(step + 1, host_state)
+                with obs.span("train.pipeline_us"):
+                    pipeline.save_state(fs)
+                history[-1]["save_s"] = time.perf_counter() - t0
     return state, history

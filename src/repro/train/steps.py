@@ -42,10 +42,11 @@ def make_train_step(model: Model, optimizer: AdamW, *, compress: bool = False):
     def step(state, batch):
         (loss, metrics), grads = jax.value_and_grad(
             model.loss, has_aux=True)(state["params"], batch)
-        if compress:
-            grads = grad_compress.compress_tree(grads)
-        updates, opt, om = optimizer.update(grads, state["opt"], state["params"])
-        params = apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            if compress:
+                grads = grad_compress.compress_tree(grads)
+            updates, opt, om = optimizer.update(grads, state["opt"], state["params"])
+            params = apply_updates(state["params"], updates)
         metrics = dict(metrics, loss=loss, **om)
         return {"params": params, "opt": opt}, metrics
 

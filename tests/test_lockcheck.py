@@ -212,6 +212,19 @@ def test_lint_l003_psync_without_pwb(tmp_path):
     assert out == [("L003", 3)]
 
 
+def test_lint_l006_timeline_span_name(tmp_path):
+    out = run_lint_snippet(tmp_path, """\
+        from repro import obs
+
+        def save(fs, blob):
+            with obs.span("ckpt.write_us", bytes=len(blob)):
+                fs.pwrite(0, blob, 0)
+            with obs.span("Checkpoint Write"):
+                pass
+        """)
+    assert out == [("L006", 6)]
+
+
 def test_lint_suppression_comment(tmp_path):
     out = run_lint_snippet(tmp_path, """\
         def odd(nvmm):
