@@ -304,6 +304,7 @@ class NVCache:
                         "drain.fsync_merged_total": "fsyncs_merged",
                         "drain.extent_total": "extents",
                         "drain.pwritev_total": "pwritevs",
+                        "drain.direct_entry_total": "direct_entries",
                         "drain.deferred_total": "deferred",
                         "drain.span_merge_total": "span_merges"},
                        lambda: {"batches": self.cleanup.stats_batches,
@@ -315,6 +316,8 @@ class NVCache:
                                     self.cleanup.stats_fsyncs_merged,
                                 "extents": self.cleanup.stats_extents,
                                 "pwritevs": self.cleanup.stats_pwritevs,
+                                "direct_entries":
+                                    self.cleanup.stats_direct_entries,
                                 "deferred": self.cleanup.stats_deferred,
                                 "span_merges":
                                     self.cleanup.stats_span_merges})
@@ -1550,6 +1553,7 @@ class NVCache:
             "cleanup_fsyncs_merged": m["drain.fsync_merged_total"],
             "drain_extents": m["drain.extent_total"],
             "drain_pwritevs": m["drain.pwritev_total"],
+            "drain_direct_entries": m["drain.direct_entry_total"],
             "drain_deferred": m["drain.deferred_total"],
             "drain_span_merges": m["drain.span_merge_total"],
             "nvmm_psyncs": m["nvmm.psync_total"],

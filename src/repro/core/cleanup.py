@@ -6,7 +6,8 @@ Each :class:`CleanupThread` consumes committed entries in log order from its
 shard's persistent tail.  Where the paper forwards them to the slow tier one
 ``pwrite`` per entry and relies on the kernel page cache to write-combine
 (§IV-C), we build an explicit :class:`~repro.core.drain.DrainPlan` — entries
-grouped by (file, page), merged into page images, coalesced into extents —
+grouped by file, coalesced into extents (through page images where a
+file's entries overlap, straight from the entries where they do not) —
 and apply it with vectored writes, so each dirty backend page is written at
 most once per batch.  Then one fsync per touched file per batch, routed
 through the pool's cross-shard :class:`~repro.core.drain.FsyncEpochScheduler`
@@ -75,6 +76,7 @@ class CleanupThread(threading.Thread):
         "stats_batches": locking.VOLATILE, "stats_entries": locking.VOLATILE,
         "stats_fsyncs": locking.VOLATILE, "stats_extents": locking.VOLATILE,
         "stats_pwritevs": locking.VOLATILE,
+        "stats_direct_entries": locking.VOLATILE,
         "stats_deferred": locking.VOLATILE,
         "stats_span_merges": locking.VOLATILE,
         # observability plane handle: set once before start() (publication
@@ -123,6 +125,8 @@ class CleanupThread(threading.Thread):
         self.stats_fsyncs = 0                 # fsyncs *requested* (pre-merge)
         self.stats_extents = 0                # extent writes issued
         self.stats_pwritevs = 0               # vectored write calls issued
+        self.stats_direct_entries = 0         # entries drained without page
+        #                                       images (non-overlapping files)
         self.stats_deferred = 0               # entries carried across batches
         self.stats_span_merges = 0            # batches that merged a carry
 
@@ -206,7 +210,8 @@ class CleanupThread(threading.Thread):
         with obs_spans.span("drain.batch_us", entries=eff) as sp:
             obs = self.obs
             lv2 = obs is not None and obs.prof.lv2
-            # phase 1: group by (file, page), materialize images, coalesce extents
+            # phase 1: group by file, coalesce extents (through page images
+            # only for a file whose entries overlap)
             t0 = time.perf_counter_ns() if lv2 else 0
             plan = _drain.build_plan(shard, start, eff, self.resolve_file, pol,
                                      abort=self._abort)
@@ -214,7 +219,9 @@ class CleanupThread(threading.Thread):
                 obs.prof.h_drain_plan.record_ns(time.perf_counter_ns() - t0)
             if plan is None:
                 return
-            sp.set(bytes=sum(fp.nbytes for fp in plan.files))
+            sp.set(bytes=sum(fp.nbytes for fp in plan.files),
+                   direct_bytes=sum(fp.nbytes for fp in plan.files
+                                    if fp.direct))
             # phase 2: extent writes under page cleanup locks + index retire
             t0 = time.perf_counter_ns() if lv2 else 0
             drained = _drain.apply_plan(plan, pol, abort=self._abort, stats=self)
@@ -267,6 +274,8 @@ class CleanupThread(threading.Thread):
                 # for the next flush() sweep
                 self.reap(f)
         self.stats_entries += sum(drained.values())
+        self.stats_direct_entries += sum(fp.entries for fp in plan.files
+                                         if fp.direct)
         self.stats_batches += 1
         self._note_deferred(start + eff, defer)
 
@@ -567,6 +576,10 @@ class CleanupPool:
     @property
     def stats_pwritevs(self) -> int:
         return sum(t.stats_pwritevs for t in self.threads)
+
+    @property
+    def stats_direct_entries(self) -> int:
+        return sum(t.stats_direct_entries for t in self.threads)
 
     @property
     def stats_deferred(self) -> int:

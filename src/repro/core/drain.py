@@ -8,12 +8,16 @@ write-combining explicit and moves it above the syscall boundary, the way
 dm-writeboost submits one bio for hundreds of data+metadata blocks:
 
 * **Phase 1 — plan** (:func:`build_plan`): walk the batch's committed
-  entries in shard-log order and group them by (file, page).  Overlapping
-  and adjacent entries are merged into *materialized page images* (the
-  paper's "the kernel combines the writes", §IV-C, done eagerly in user
-  space), and runs of contiguous pages are coalesced into *extents*, so
-  each dirty backend page is written at most once per batch no matter how
-  many small log entries touched it.
+  entries in shard-log order and group them by file.  A file whose entries
+  overlap is grouped by page: its entries are merged into *materialized
+  page images* (the paper's "the kernel combines the writes", §IV-C, done
+  eagerly in user space, later entries winning), and runs of contiguous
+  pages are coalesced into *extents*, so each dirty backend page is
+  written at most once per batch no matter how many small log entries
+  touched it.  A file whose entries are pairwise non-overlapping (a
+  sequential ``pwrite`` stream: checkpoints, appended log lines) needs no
+  page images: its entries, sorted by offset, are joined straight into the
+  same extents, cut at the same page boundaries, copying each byte once.
 * **Phase 2 — apply** (:func:`apply_plan`): take the cleanup locks of the
   affected pages (the reader/cleanup exclusion of §II-D), issue the extents
   as vectored ``pwritev`` calls (one syscall per file per batch instead of
@@ -38,7 +42,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from operator import itemgetter
+from typing import Callable, Dict, Iterable, List, Optional
 
 from repro.core import locking
 from repro.core.log import CG_HEAD, META_FDID, LogShard
@@ -56,8 +61,9 @@ AbortFn = Callable[[str], bool]
 
 
 class Extent:
-    """One contiguous backend write: merged bytes plus, per covered page,
-    the entry indices whose refs it retires once written."""
+    """One contiguous backend write: merged bytes (bytes-like) plus, per
+    covered page in ascending order, the entry indices whose refs it
+    retires once written."""
 
     __slots__ = ("off", "data", "pages", "retire")
 
@@ -73,13 +79,14 @@ class Extent:
 
 
 class FilePlan:
-    __slots__ = ("file", "extents", "entries", "nbytes")
+    __slots__ = ("file", "extents", "entries", "nbytes", "direct")
 
     def __init__(self, file):
         self.file = file
         self.extents: List[Extent] = []
         self.entries = 0              # log entries drained for this file
         self.nbytes = 0
+        self.direct = False           # extents built without page images
 
 
 class DrainPlan:
@@ -125,11 +132,11 @@ class _PageImage:
 
 
 class _FileAcc:
-    __slots__ = ("file", "pages", "raw", "entries", "nbytes")
+    __slots__ = ("file", "ents", "raw", "entries", "nbytes")
 
     def __init__(self, file):
         self.file = file
-        self.pages: Dict[int, _PageImage] = {}
+        self.ents: List[tuple] = []     # (off, end, data, idx), log order
         self.raw: List[tuple] = []      # legacy mode: (off, bytes, idx)
         self.entries = 0
         self.nbytes = 0
@@ -205,8 +212,9 @@ def build_plan(shard: LogShard, start: int, run: int,
                resolve_file: Callable[[int], Optional[object]],
                policy: Policy, *, abort: Optional[AbortFn] = None
                ) -> Optional[DrainPlan]:
-    """Phase 1: group the batch's committed entries by (file, page), merge
-    them into page images, and coalesce page runs into extents.
+    """Phase 1: group the batch's committed entries by file and coalesce
+    them into extents: directly where the file's entries do not overlap,
+    through page images where they do.
 
     Returns ``None`` if ``abort`` fired (power loss / fault injection):
     nothing has been written or retired, the log replays the batch.
@@ -236,23 +244,93 @@ def build_plan(shard: LogShard, start: int, run: int,
         if not policy.drain_coalesce:
             acc.raw.append((e.off, bytes(e.data), e.idx))
             continue
-        p0, p1 = e.off // ps, (e.off + e.length - 1) // ps
-        for p in range(p0, p1 + 1):
-            img = acc.pages.get(p)
-            if img is None:
-                img = acc.pages[p] = _PageImage(ps)
-            base = p * ps
-            s, t = max(e.off, base), min(e.off + e.length, base + ps)
-            img.add(s - base, t - base, e.data[s - e.off:t - e.off], e.idx)
+        acc.ents.append((e.off, e.off + e.length, e.data, e.idx))
 
     for acc in order:
         fp = FilePlan(acc.file)
         fp.entries = acc.entries
         fp.nbytes = acc.nbytes
-        fp.extents = (_coalesced_extents(acc, ps, policy.coalesce_max_extent)
-                      if policy.drain_coalesce else _raw_extents(acc, ps))
+        if not policy.drain_coalesce:
+            fp.extents = _raw_extents(acc, ps)
+        else:
+            ents = sorted(acc.ents, key=itemgetter(0))
+            fp.direct = _disjoint(ents)
+            fp.extents = (
+                _direct_extents(ents, ps, policy.coalesce_max_extent)
+                if fp.direct else
+                _coalesced_extents(_page_images(acc.ents, ps), ps,
+                                   policy.coalesce_max_extent))
         plan.files.append(fp)
     return plan
+
+
+def _disjoint(ents: List[tuple]) -> bool:
+    """Whether offset-sorted ``(off, end, ...)`` entries are pairwise
+    non-overlapping (touching is not overlapping)."""
+    end = -1
+    for off, e, _d, _i in ents:
+        if off < end:
+            return False
+        end = e
+    return True
+
+
+def _direct_extents(ents: List[tuple], ps: int,
+                    max_extent: int) -> List[Extent]:
+    """Extents of offset-sorted, pairwise non-overlapping entries, with no
+    page images.  Each maximal contiguous run of entries is cut greedily at
+    page boundaries, exactly where :func:`_coalesced_extents` cuts it: an
+    extent starting at ``s`` takes the whole rest of the run if that fits
+    in ``max_extent`` bytes, else ends at the last page boundary within
+    ``s + max_extent``.  Each byte is copied once (the join), and a page's
+    retire list comes from the entries' offsets, not from merged ranges."""
+    out: List[Extent] = []
+    n = len(ents)
+    i = 0
+    while i < n:
+        a, b = ents[i][0], ents[i][1]
+        j = i + 1
+        while j < n and ents[j][0] == b:      # one maximal contiguous run
+            b = ents[j][1]
+            j += 1
+        s = a
+        while s < b:
+            x = b if b - s <= max_extent else (s + max_extent) // ps * ps
+            parts = []
+            retire: Dict[int, List[int]] = {}
+            while i < j:
+                off, end, data, idx = ents[i]
+                lo, hi = max(off, s), min(end, x)
+                parts.append(data[lo - off:hi - off]
+                             if lo > off or hi < end else data)
+                for p in range(lo // ps, (hi - 1) // ps + 1):
+                    r = retire.get(p)
+                    if r is None:
+                        retire[p] = [idx]
+                    else:
+                        r.append(idx)
+                if end > x:                   # the rest starts the next one
+                    break
+                i += 1
+            out.append(Extent(s, b"".join(parts), list(retire), retire))
+            s = x
+        i = j
+    return out
+
+
+def _page_images(ents: List[tuple], ps: int) -> Dict[int, _PageImage]:
+    """Materialize the pages that log-ordered ``(off, end, data, idx)``
+    entries touch, later entries winning where they overlap."""
+    pages: Dict[int, _PageImage] = {}
+    for off, end, data, idx in ents:
+        for p in range(off // ps, (end - 1) // ps + 1):
+            img = pages.get(p)
+            if img is None:
+                img = pages[p] = _PageImage(ps)
+            base = p * ps
+            s, t = max(off, base), min(end, base + ps)
+            img.add(s - base, t - base, data[s - off:t - off], idx)
+    return pages
 
 
 def _raw_extents(acc: _FileAcc, ps: int) -> List[Extent]:
@@ -267,7 +345,8 @@ def _raw_extents(acc: _FileAcc, ps: int) -> List[Extent]:
     return out
 
 
-def _coalesced_extents(acc: _FileAcc, ps: int, max_extent: int) -> List[Extent]:
+def _coalesced_extents(pages: Dict[int, _PageImage], ps: int,
+                       max_extent: int) -> List[Extent]:
     """Flatten materialized page images into maximal contiguous extents."""
     out: List[Extent] = []
     cur_off = cur_end = 0
@@ -281,8 +360,8 @@ def _coalesced_extents(acc: _FileAcc, ps: int, max_extent: int) -> List[Extent]:
             out.append(Extent(cur_off, cur_data, cur_pages, cur_retire))
             cur_data = None
 
-    for p in sorted(acc.pages):
-        img = acc.pages[p]
+    for p in sorted(pages):
+        img = pages[p]
         base = p * ps
         for s, e in img.ranges:
             abs_s, abs_e = base + s, base + e
@@ -335,7 +414,7 @@ def apply_plan(plan: DrainPlan, policy: Policy, *,
     return drained
 
 
-def _lock_descs(f, pages: List[int]):
+def _lock_descs(f, pages: Iterable[int]):
     """Cleanup locks for ``pages``, ascending; returns [(page, desc)]."""
     if f.radix is None:
         return []
@@ -354,17 +433,32 @@ def _lock_descs(f, pages: List[int]):
 VEC_CHUNK = 64
 
 
+def _chunk_retire(chunk: List[Extent]) -> Dict[int, List[int]]:
+    """page -> entry indices to retire, over a chunk of a file's extents.
+    Extents ascend by offset and each one's pages ascend, so the keys come
+    out ascending: the lock order.  Two extents share a page only where one
+    contiguous run ends and the next begins inside it."""
+    if len(chunk) == 1:
+        return chunk[0].retire
+    out: Dict[int, List[int]] = {}
+    for ext in chunk:
+        for p, idxs in ext.retire.items():
+            have = out.get(p)
+            out[p] = idxs if have is None else have + idxs
+    return out
+
+
 def _apply_vectored(plan, fp, pwritev, abort, stats) -> bool:
-    """A file's extents in chunks: one lock hold + one pwritev per chunk."""
+    """A file's extents in chunks: one lock hold + one pwritev per chunk,
+    then one retire pass per page descriptor."""
     obs = getattr(stats, "obs", None)
     lv2 = obs is not None and obs.prof.lv2
     for i in range(0, len(fp.extents), VEC_CHUNK):
         chunk = fp.extents[i:i + VEC_CHUNK]
         if abort is not None and abort(APPLY_EXTENT):
             return False
-        pages = sorted({p for ext in chunk for p in ext.pages})
-        descs = _lock_descs(fp.file, pages)
-        dmap = dict(descs)
+        retire = _chunk_retire(chunk)
+        descs = _lock_descs(fp.file, retire)
         try:
             t0 = time.perf_counter_ns() if lv2 else 0
             pwritev([(ext.data, ext.off) for ext in chunk])
@@ -376,11 +470,10 @@ def _apply_vectored(plan, fp, pwritev, abort, stats) -> bool:
                 stats.stats_extents += len(chunk)
             if abort is not None and abort(APPLY_RETIRE):
                 return False
-            for ext in chunk:
-                for p, idxs in ext.retire.items():
-                    d = dmap.get(p)
-                    if d is not None:
-                        d.retire_refs(plan.sid, set(idxs))
+            for (_p, d), idxs in zip(descs, retire.values()):
+                # a short list beats building a set for the membership test
+                d.retire_refs(plan.sid,
+                              idxs if len(idxs) <= 8 else set(idxs))
         finally:
             for _p, d in reversed(descs):
                 d.cleanup_lock.release()

@@ -3,15 +3,20 @@
 Covers: O(entries-on-page) dirty-miss replay with zero whole-log scans,
 per-page entry-ref retire accounting across K shards, dirty-miss reads
 racing a concurrent drain (never torn, never stale), extent coalescing
-reducing backend page writes, fsync epoch merging, and the two tier-model
-satellite fixes (truncate page-state cleanup, DMWriteCacheTier re-wrap).
+reducing backend page writes, the direct plan for non-overlapping entries
+(the same extents and device work as the page-image plan), fsync epoch
+merging, and the two tier-model satellite fixes (truncate page-state
+cleanup, DMWriteCacheTier re-wrap).
 """
+import random
 import threading
 import struct
 
 import pytest
 
 from repro.core import NVCache, Policy
+from repro.core import drain as drain_mod
+from repro.core.cleanup import CleanupThread
 from repro.core.drain import FsyncEpochScheduler
 from repro.storage.tiers import (DMWriteCacheTier, DRAM, PAGE, SSD_SATA,
                                  Tier, TierFile)
@@ -213,6 +218,191 @@ def test_overlapping_writes_in_one_batch_drain_in_commit_order():
     snap = f.snapshot()
     assert snap[50:120] == b"\x05" * 70
     assert snap[120:180] == b"\x77" * 60
+    nv.shutdown()
+
+
+# ------------------------------------------------------------- direct plan
+DPS = 256                                   # page size of the direct-plan runs
+DED = 256 - 48                              # entry_data
+PATHS = ("/a", "/b", "/c")
+
+
+def _detached_nv(max_extent: int):
+    """An NVCache whose pool threads are stopped, plus an unstarted
+    CleanupThread over shard 0 that the test steps by hand, so batch
+    boundaries (and carried tails) are exactly the test's choices."""
+    pol = Policy(entry_size=256, log_entries=1024, page_size=DPS,
+                 read_cache_pages=4, batch_min=10 ** 6, batch_max=10 ** 6,
+                 coalesce_max_extent=max_extent,
+                 coalesce_deadline_ms=10_000.0)
+    tier = Tier(DRAM)
+    for path in PATHS:
+        tier.open(path)      # pre-exist: open() journals no create record
+    nv = NVCache(pol, tier)
+    for th in nv.cleanup.threads:
+        th.hard_stop.set()
+        th.stop_event.set()
+        th.shard.notify_committed()
+    for th in nv.cleanup.threads:
+        th.join(timeout=10)
+    return nv, tier, CleanupThread(nv.log, nv.log.shards[0], nv._resolve_fdid)
+
+
+def _ops(seed: int):
+    """A seeded mix, in application order: ``("w", path, off, data)``,
+    ``("z", path, off)`` for a zero-length log entry, and ``("step",
+    limit)`` for one hand-stepped drain batch of at most ``limit``
+    entries.  /a is an append stream with occasional gaps (runs that end
+    and begin inside one page) and a rare overlapping rewrite; /b appends
+    short lines that share pages at their boundaries; /c is rewritten at
+    offset 0, so its batches overlap."""
+    rng = random.Random(seed)
+    ends = {"/a": rng.randrange(0, DPS), "/b": 0, "/c": 0}
+    out = []
+    for _ in range(rng.randint(60, 120)):
+        r = rng.random()
+        if r < 0.45:
+            path = "/a"
+            off = ends["/a"]
+            if rng.random() < 0.1:
+                off += rng.randint(1, DPS // 2)           # gap
+            elif rng.random() < 0.05:
+                off = rng.randrange(0, max(1, off))      # overlapping rewrite
+            elif rng.random() < 0.05:
+                off = max(0, off - 1)                    # overlaps by a byte
+            data = bytes([rng.randrange(1, 256)]) * rng.randint(1, 3 * DED)
+        elif r < 0.75:
+            path, off = "/b", ends["/b"]
+            data = bytes([rng.randrange(1, 256)]) * rng.randint(20, 120)
+        elif r < 0.85:
+            path, off = "/c", 0
+            data = bytes([rng.randrange(1, 256)]) * rng.randint(50, 300)
+        elif r < 0.9:
+            path = rng.choice(("/a", "/b"))
+            out.append(("z", path, ends[path]))
+            continue
+        else:
+            out.append(("step", rng.randint(1, 48)))
+            continue
+        ends[path] = max(ends[path], off + len(data))
+        out.append(("w", path, off, data))
+    return out
+
+
+def _drive(ops, max_extent: int, monkeypatch, *, page_images: bool):
+    """Run ``ops`` through a detached drain; returns each batch's extents
+    per file, the tier, the drain thread and the written bytes' replay."""
+    nv, tier, t = _detached_nv(max_extent)
+    fds = {p: nv.open(p) for p in PATHS}
+    files = {nv._files[p]: p for p in PATHS}
+    sh = nv.log.shards[0]
+    batches = []
+    build = drain_mod.build_plan
+
+    def record(*a, **kw):
+        plan = build(*a, **kw)
+        batches.append([(files[fp.file], fp.direct,
+                         [(x.off, bytes(x.data), list(x.pages),
+                           {p: sorted(i) for p, i in x.retire.items()})
+                          for x in fp.extents]) for fp in plan.files])
+        return plan
+
+    with monkeypatch.context() as m:
+        m.setattr(drain_mod, "build_plan", record)
+        if page_images:
+            m.setattr(drain_mod, "_disjoint", lambda ents: False)
+
+        def step(limit):
+            run = sh.committed_run(sh.persistent_tail, limit)
+            if run:
+                t._consume_batch(run)
+            # every drained entry's refs are gone from every page
+            for f in files:
+                for p in range(f.hwm // DPS + 2):
+                    d = f.radix.get(p)
+                    assert d is None or not any(
+                        r.sid == sh.sid and r.idx < sh.persistent_tail
+                        for r in d.snapshot_refs()), (files[f], p)
+
+        replay = {p: bytearray() for p in PATHS}
+        for op in ops:
+            if op[0] == "step":
+                step(op[1])
+            elif op[0] == "z":
+                f = nv._files[op[1]]
+                nv.log.append(f.fdid, op[2], b"", shard=0)
+                f.pending.inc(1)
+            else:
+                _, path, off, data = op
+                nv.pwrite(fds[path], data, off)
+                img = replay[path]
+                if off + len(data) > len(img):
+                    img.extend(bytes(off + len(data) - len(img)))
+                img[off:off + len(data)] = data
+        t.drain_event.set()                   # a barrier: flush any carry
+        while sh.committed_run(sh.persistent_tail, 10 ** 6):
+            step(10 ** 6)
+    assert nv.log.used_entries == 0
+    nv.shutdown()
+    return batches, tier, t, replay
+
+
+@pytest.mark.parametrize("max_extent", [DPS, 3 * DPS + 100, 1 << 20])
+@pytest.mark.parametrize("seed", range(4))
+def test_direct_plan_matches_page_images(seed, max_extent, monkeypatch):
+    """Seeded batches mixing append streams, entries sharing a page at
+    their boundary, overlapping rewrites, zero-length entries and carried
+    tails: the adaptive plan writes the bytes of an in-order replay, and
+    gives the tier the extents, the write calls, the dirty pages and the
+    fsyncs that the page-image plan gives for the same batches."""
+    ops = _ops(seed)
+    got, tier, t, replay = _drive(ops, max_extent, monkeypatch,
+                                  page_images=False)
+    ref, rtier, rt, _ = _drive(ops, max_extent, monkeypatch,
+                               page_images=True)
+    assert [[(p, x) for p, _d, x in b] for b in got] == \
+        [[(p, x) for p, _d, x in b] for b in ref]
+    directs = {d for b in got for _p, d, _x in b}
+    assert directs == {True, False}, "both plans must be exercised"
+    assert not any(d for b in ref for _p, d, _x in b)
+    assert t.stats_direct_entries > 0 and rt.stats_direct_entries == 0
+    assert t.stats_span_merges > 0, "no carried tail was exercised"
+    for path in PATHS:
+        a, b = tier.open(path), rtier.open(path)
+        assert a.snapshot()[:len(replay[path])] == bytes(replay[path])
+        assert not a.snapshot()[len(replay[path]):].strip(b"\x00")
+        assert a.snapshot() == b.snapshot()
+        for k in ("stats_writes", "stats_page_writes", "stats_wvec_segments",
+                  "stats_fsyncs"):
+            assert getattr(a, k) == getattr(b, k), (path, k)
+
+
+@pytest.mark.parametrize("kind", ["append", "rewrite"])
+def test_direct_entries_counter(kind):
+    """``stats_direct_entries`` counts an append stream's entries, and
+    stays 0 for a file rewritten at offset 0 within one batch."""
+    nv, tier, t = _detached_nv(1 << 20)
+    fd = nv.open("/a")
+    sh = nv.log.shards[0]
+    for i in range(6):
+        off = i * 300 if kind == "append" else 0
+        nv.pwrite(fd, bytes([i + 1]) * 300, off)
+    t.drain_event.set()
+    n = sh.committed_run(sh.persistent_tail, 10 ** 6)
+    t._consume_batch(n)
+    assert t.stats_entries == n
+    assert t.stats_direct_entries == (n if kind == "append" else 0)
+    nv.shutdown()
+    # the pool sums it and stats() reports it
+    nv = NVCache(make_policy(2), Tier(DRAM))
+    fd = nv.open("/a")
+    for i in range(6):
+        nv.pwrite(fd, bytes([i + 1]) * 300, i * 300 if kind == "append" else 0)
+    nv.flush()
+    direct = nv.stats()["drain_direct_entries"]
+    assert direct == nv.cleanup.stats_direct_entries
+    if kind == "append":
+        assert direct == nv.stats()["cleanup_entries"] > 0
     nv.shutdown()
 
 

@@ -105,17 +105,32 @@ def test_power_loss_at_any_plan_apply_point_loses_nothing(k):
     assert {drain_mod.FSYNC, drain_mod.CONSUME} & seen_tags
 
 
-@pytest.mark.parametrize("tag", [drain_mod.PLAN_ENTRY, drain_mod.APPLY_FILE,
-                                 drain_mod.APPLY_EXTENT,
-                                 drain_mod.APPLY_RETIRE, drain_mod.FSYNC,
-                                 drain_mod.CONSUME])
-def test_power_loss_pinned_at_each_checkpoint(tag):
+TAGS = [drain_mod.PLAN_ENTRY, drain_mod.APPLY_FILE, drain_mod.APPLY_EXTENT,
+        drain_mod.APPLY_RETIRE, drain_mod.FSYNC, drain_mod.CONSUME]
+
+
+@pytest.mark.parametrize("tag,stream",
+                         [pytest.param(tag, "random", id=tag) for tag in TAGS]
+                         + [pytest.param(tag, "append", id=f"{tag}-append")
+                            for tag in TAGS])
+def test_power_loss_pinned_at_each_checkpoint(tag, stream, monkeypatch):
     """Deterministic variant: die at the FIRST occurrence of one specific
-    checkpoint, for every checkpoint the engine defines."""
+    checkpoint, for every checkpoint the engine defines; ``append`` is a
+    sequential stream, which the drain plans without page images."""
     pol = make_policy(2, "stripe")
     tier = Tier(DRAM)
     nv = NVCache(pol, tier, track_crashes=True)
     hit = threading.Event()
+    direct = []
+    build = drain_mod.build_plan
+
+    def record(*a, **kw):
+        plan = build(*a, **kw)
+        if plan is not None:
+            direct.extend(fp.direct for fp in plan.files)
+        return plan
+
+    monkeypatch.setattr(drain_mod, "build_plan", record)
 
     def hook(t):
         if t == tag:
@@ -129,14 +144,21 @@ def test_power_loss_pinned_at_each_checkpoint(tag):
     fd = nv.open("/f")
     ops = []
     rng = random.Random(42)
+    off = 37
     for _ in range(12):
-        off = rng.randrange(0, 900)
-        data = bytes([rng.randrange(1, 256)]) * rng.randint(1, 400)
+        n = rng.randint(1, 400)
+        if stream == "random":
+            off = rng.randrange(0, 900)
+        data = bytes([rng.randrange(1, 256)]) * n
         nv.pwrite(fd, data, off)
         ops.append((off, data))
+        if stream == "append":
+            off += n
     nv.cleanup.request_drain()
     assert hit.wait(timeout=30), f"checkpoint {tag} never reached"
     nvmm = nv.crash()
+    if stream == "append":                # planned, unless it died planning
+        assert all(direct) and (direct or tag == drain_mod.PLAN_ENTRY)
     tier2 = Tier(DRAM)
     for path in tier.paths():
         snap = tier.open(path).snapshot()
