@@ -16,6 +16,7 @@ ARCHS = {
     "hymba-1.5b": "hymba_1_5b",
     "qwen2-vl-7b": "qwen2_vl_7b",
     "mamba2-780m": "mamba2_780m",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

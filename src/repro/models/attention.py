@@ -93,10 +93,14 @@ def mla_init(cfg: ModelConfig, key):
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nd, rd, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     ks = jax.random.split(key, 6)
+    if qr:
+        q = {"wq_a": dense_init(ks[0], (d, qr), d, cfg.pdt),
+             "q_norm": jnp.ones((qr,), cfg.pdt),
+             "wq_b": dense_init(ks[1], (qr, H * (nd + rd)), qr, cfg.pdt)}
+    else:                                  # no query compression (DeepSeek-V3 lite)
+        q = {"wq": dense_init(ks[1], (d, H * (nd + rd)), d, cfg.pdt)}
     return {
-        "wq_a": dense_init(ks[0], (d, qr), d, cfg.pdt),
-        "q_norm": jnp.ones((qr,), cfg.pdt),
-        "wq_b": dense_init(ks[1], (qr, H * (nd + rd)), qr, cfg.pdt),
+        **q,
         "wkv_a": dense_init(ks[2], (d, kvr + rd), d, cfg.pdt),
         "kv_norm": jnp.ones((kvr,), cfg.pdt),
         "wkv_b": dense_init(ks[3], (kvr, H * (nd + vd)), kvr, cfg.pdt),
@@ -108,8 +112,11 @@ def _mla_q(cfg, p, x, rope):
     from repro.models.layers import rmsnorm
     B, S, _ = x.shape
     H, nd, rd = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    ql = rmsnorm(x @ p["wq_a"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
-    q = (ql @ p["wq_b"].astype(x.dtype)).reshape(B, S, H, nd + rd)
+    if "wq" in p:
+        q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, nd + rd)
+    else:
+        ql = rmsnorm(x @ p["wq_a"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
+        q = (ql @ p["wq_b"].astype(x.dtype)).reshape(B, S, H, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
     if rope is not None:
         cos, sin = rope
@@ -152,7 +159,8 @@ def mla_forward(cfg: ModelConfig, p, x, rope=None, *, causal=True,
     k = pctx.constrain(k, ("__dp__", None, "model", None))
     v = pctx.constrain(v, ("__dp__", None, "model", None))
     o = blocked_attention(q, k, v, causal=causal, block=cfg.attn_block,
-                          scale=1.0 / ((nd + rd) ** 0.5))[:, :, :H, :]
+                          scale=1.0 / ((nd + rd) ** 0.5),
+                          recompute=cfg.attn_recompute)[:, :, :H, :]
     out = o.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
     if return_kv:
         return out, (ckv, k_rope)

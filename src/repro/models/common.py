@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -27,6 +27,7 @@ class ModelConfig:
     swa_window: int = 0              # 0 = full attention
     global_layers: Tuple[int, ...] = ()   # hybrid: layers with full attention
     attn_block: int = 1024           # kv-block for blocked (flash-style) attn
+    attn_recompute: bool = False     # backward recomputes each kv-block's scores
     # --- TP-friendliness (see EXPERIMENTS.md §Perf) ---------------------
     # repeat KV heads to full H in the train/prefill path so the attention
     # einsums shard over the model axis even when n_kv_heads < TP degree
@@ -37,7 +38,7 @@ class ModelConfig:
     pad_heads_to: int = 0
 
     # -- MLA (MiniCPM3 / DeepSeek style) ------------------------------------
-    q_lora_rank: int = 0
+    q_lora_rank: Optional[int] = 0   # 0 or None: the query is projected from d
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0
@@ -50,6 +51,18 @@ class ModelConfig:
     dense_residual: bool = False     # Arctic: dense MLP in parallel with MoE
     capacity_factor: float = 1.25
     moe_group: int = 2048            # tokens per dispatch group
+    # DeepSeek-V3 expert layer (router "sigmoid"): float32 sigmoid scores,
+    # top-k chosen on score + a bias buffer, weights from the unbiased scores
+    # normalised over the k and scaled; shared experts; dropless dispatch
+    # over the experts this chip holds; sequence-wise balance loss
+    router: str = "softmax"          # softmax (capacity dispatch) | sigmoid
+    routed_scale: float = 1.0
+    n_shared_experts: int = 0        # one SwiGLU of n_shared_experts * d_expert
+    first_dense: int = 0             # leading dense layers (SwiGLU of d_ff)
+    experts_held: int = 0            # experts this chip computes (0: all) ...
+    expert_first: int = 0            # ... from this one on
+    aux_weight: float = 0.01         # weight of the balance loss in the loss
+    bias_rate: float = 0.0           # gamma of the router-bias update per step
 
     # -- SSM (Mamba2 / SSD) ---------------------------------------------------
     ssm_state: int = 0
@@ -84,6 +97,10 @@ class ModelConfig:
         return jnp.dtype(self.compute_dtype)
 
     @property
+    def held(self) -> int:           # routed experts whose weights live here
+        return self.experts_held or self.n_experts
+
+    @property
     def d_inner(self) -> int:        # SSM inner width
         return self.ssm_expand * self.d_model
 
@@ -103,9 +120,7 @@ class ModelConfig:
         mlp = 3 * d * self.d_ff if self.d_ff else 0
         per = att + mlp + 2 * d
         if self.family == "moe":
-            per = att + 2 * d + d * self.n_experts + self.n_experts * 3 * d * self.d_expert
-            if self.dense_residual:
-                per += 3 * d * self.d_ff
+            per = self._moe_layer_params(self.held)
         if self.family == "hybrid":
             di, n = self.d_inner, self.ssm_state
             ssm = d * (2 * di + 2 * n + self.ssm_heads) + di * (self.ssm_conv + 1) \
@@ -115,12 +130,26 @@ class ModelConfig:
         if self.family == "encdec":
             layers = self.enc_layers + self.dec_layers
             per += att + d          # cross-attention + extra norm (decoder avg.)
-        return emb + layers * per + d
+        return emb + self._dense_lead() + (layers - self.first_dense) * per + d
+
+    def _dense_lead(self) -> int:
+        """The leading dense layers of an expert model."""
+        return self.first_dense * (self._attn_params() + 3 * self.d_model * self.d_ff
+                                   + 2 * self.d_model)
+
+    def _moe_layer_params(self, experts: int) -> int:
+        d = self.d_model
+        per = self._attn_params() + 2 * d + d * self.n_experts \
+            + (experts + self.n_shared_experts) * 3 * d * self.d_expert
+        if self.dense_residual:
+            per += 3 * d * self.d_ff
+        return per
 
     def _attn_params(self) -> int:
         d, hd = self.d_model, self.head_dim
         if self.attn_kind == "mla":
-            q = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
+            qk = self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
+            q = (d * self.q_lora_rank + self.q_lora_rank * qk) if self.q_lora_rank else d * qk
             kv = d * (self.kv_lora_rank + self.qk_rope_dim) \
                 + self.kv_lora_rank * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
             o = self.n_heads * self.v_head_dim * d
@@ -134,9 +163,6 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
-        att = self._attn_params()
-        per = att + 2 * d + d * self.n_experts + self.top_k * 3 * d * self.d_expert
-        if self.dense_residual:
-            per += 3 * d * self.d_ff
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        return emb + self.n_layers * per + d
+        return emb + self._dense_lead() \
+            + (self.n_layers - self.first_dense) * self._moe_layer_params(self.top_k) + d

@@ -79,13 +79,15 @@ def swiglu(x, wg, wu, wd):
 def blocked_attention(q, k, v, *, causal: bool, window=None,
                       block: int = 1024, q_offset=0,
                       kv_len: Optional[jax.Array] = None,
-                      scale: Optional[float] = None):
+                      scale: Optional[float] = None, recompute: bool = False):
     """Online-softmax attention over KV blocks (memory O(S·block)).
 
     q: (B, Sq, H, D); k, v: (B, Skv, KV, D) with H % KV == 0 (GQA).
     ``q_offset``: global position of q[0] (prefill continuation / decode).
     ``window`` > 0: sliding-window attention (key j visible to query i iff
     i - window < j <= i).  ``kv_len``: valid prefix length of k/v (padding).
+    ``recompute``: the backward pass recomputes each block's scores instead
+    of keeping all Sq x Skv probabilities (for long training sequences).
     Returns (B, Sq, H, D) in q.dtype.
     """
     B, Sq, H, D = q.shape
@@ -133,8 +135,8 @@ def blocked_attention(q, k, v, *, causal: bool, window=None,
     m0 = jnp.full((B, KV, G, Sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
     a0 = jnp.zeros((B, KV, G, Sq, Dv), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0),
-                                  (kb, vb, jnp.arange(nblk)))
+    (m, l, acc), _ = jax.lax.scan(jax.checkpoint(step) if recompute else step,
+                                  (m0, l0, a0), (kb, vb, jnp.arange(nblk)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dv).astype(q.dtype)
 

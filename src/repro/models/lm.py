@@ -1,7 +1,14 @@
 """Decoder-only LM assembly for the dense / moe / ssm / hybrid / vlm
 families: scan-over-stacked-layers (one-layer HLO regardless of depth),
 configurable remat, and three entry points — ``forward`` (train),
-``prefill`` (build caches), ``decode_step`` (one token)."""
+``prefill`` (build caches), ``decode_step`` (one token).
+
+An expert model may lead with ``first_dense`` dense layers
+(``params["dense_layers"]``), scanned before the stack of expert layers
+(``params["layers"]``).  A DeepSeek-V3 expert layer also reads a buffer
+that the optimizer does not own: its router bias, ``buffers["router_bias"]``
+(one row per expert layer), updated after each step from the step's
+per-expert loads (``update_buffers``)."""
 from __future__ import annotations
 
 import functools
@@ -21,7 +28,7 @@ NEG_WINDOW_OFF = 1 << 30   # "window" value that disables windowing
 
 # ------------------------------------------------------------------- params
 
-def _layer_init(cfg: ModelConfig, key):
+def _layer_init(cfg: ModelConfig, key, dense: bool = False):
     ks = jax.random.split(key, 8)
     p = {"norm1": jnp.ones((cfg.d_model,), cfg.pdt)}
     if cfg.family == "ssm":
@@ -32,7 +39,7 @@ def _layer_init(cfg: ModelConfig, key):
     else:
         p["attn"] = attn.gqa_init(cfg, ks[0])
     p["norm2"] = jnp.ones((cfg.d_model,), cfg.pdt)
-    if cfg.family == "moe":
+    if cfg.family == "moe" and not dense:
         p["moe"] = moe_mod.moe_init(cfg, ks[1])
         if cfg.dense_residual:
             p["mlp"] = _mlp_init(cfg, ks[2])
@@ -55,12 +62,14 @@ def _mlp_init(cfg, key):
 
 def init_lm(cfg: ModelConfig, key):
     k_emb, k_layers, k_un = jax.random.split(key, 3)
+    keys, n = jax.random.split(k_layers, cfg.n_layers), cfg.first_dense
     params = {
         "embed": dense_init(k_emb, (cfg.vocab, cfg.d_model), cfg.d_model, cfg.pdt),
         "final_norm": jnp.ones((cfg.d_model,), cfg.pdt),
-        "layers": jax.vmap(lambda k: _layer_init(cfg, k))(
-            jax.random.split(k_layers, cfg.n_layers)),
+        "layers": jax.vmap(lambda k: _layer_init(cfg, k))(keys[n:]),
     }
+    if n:
+        params["dense_layers"] = jax.vmap(lambda k: _layer_init(cfg, k, dense=True))(keys[:n])
     if not cfg.tie_embeddings:
         params["unembed"] = dense_init(k_un, (cfg.d_model, cfg.vocab),
                                        cfg.d_model, cfg.pdt)
@@ -68,6 +77,34 @@ def init_lm(cfg: ModelConfig, key):
         params["pos_table"] = (0.02 * jax.random.normal(
             k_un, (cfg.max_positions, cfg.d_model))).astype(cfg.pdt)
     return params
+
+
+def init_buffers(cfg: ModelConfig):
+    """State that the step updates outside the optimizer: the router bias
+    of each expert layer (DeepSeek-V3's auxiliary-loss-free balancing), or
+    None for a model without it."""
+    if cfg.router != "sigmoid":
+        return None
+    return {"router_bias": jnp.zeros((cfg.n_layers - cfg.first_dense, cfg.n_experts),
+                                     jnp.float32)}
+
+
+def update_buffers(cfg: ModelConfig, buffers, load):
+    """b_i += bias_rate * sign(mean load - load_i), in each expert layer,
+    over the loads this chip routed.  load: (expert layers, n_experts)."""
+    step = cfg.bias_rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
+    return {"router_bias": buffers["router_bias"] + step}
+
+
+def moe_counters(cfg: ModelConfig, stats) -> dict:
+    """The step's dispatch counters over all expert layers: rows the held
+    experts' grouped matmuls were given, the rows of the busiest held
+    expert in any layer, and the rows routed to a held expert whose output
+    came back all zero (counted from the outputs, not from the routing)."""
+    held = stats["load"][:, cfg.expert_first:cfg.expert_first + cfg.held]
+    return {"moe_rows": jnp.sum(stats["rows"]),
+            "moe_load_max": jnp.max(held).astype(jnp.int32),
+            "moe_dropped": jnp.sum(stats["dropped"]).astype(jnp.int32)}
 
 
 def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
@@ -83,16 +120,18 @@ def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
 
 # -------------------------------------------------------------------- block
 
-def _block(cfg: ModelConfig, pl, x, rope, window, *, return_kv=False):
-    """One transformer block, full-sequence path.  Returns (x, aux, kv)."""
-    aux = jnp.float32(0.0)
+def _block(cfg: ModelConfig, pl, x, rope, window, bias=None, *, return_kv=False):
+    """One transformer block, full-sequence path.  Returns (x, stats, kv):
+    ``stats`` holds the balance loss ``aux`` and, for a dropless expert
+    layer, its ``load`` and ``rows``."""
+    stats = {"aux": jnp.float32(0.0)}
     kv = None
     if cfg.family == "ssm":
         out = ssm_mod.ssm_forward(cfg, pl["ssm"], rmsnorm(x, pl["norm1"], cfg.norm_eps),
                                   return_state=return_kv)
         if return_kv:
             out, kv = out
-        return x + out, aux, kv
+        return x + out, stats, kv
 
     with jax.named_scope("attention"):
         h = rmsnorm(x, pl["norm1"], cfg.norm_eps)
@@ -114,8 +153,8 @@ def _block(cfg: ModelConfig, pl, x, rope, window, *, return_kv=False):
 
     with jax.named_scope("mlp"):
         h2 = rmsnorm(x, pl["norm2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            m, aux = moe_mod.moe_forward(cfg, pl["moe"], h2)
+        if "moe" in pl:
+            m, stats = _moe(cfg, pl["moe"], h2, bias)
             if cfg.dense_residual:
                 m = m + swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
                                pl["mlp"]["wu"].astype(x.dtype),
@@ -124,7 +163,15 @@ def _block(cfg: ModelConfig, pl, x, rope, window, *, return_kv=False):
             m = swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
                        pl["mlp"]["wu"].astype(x.dtype),
                        pl["mlp"]["wd"].astype(x.dtype))
-    return x + m, aux, kv
+    return x + m, stats, kv
+
+
+def _moe(cfg: ModelConfig, p, h, bias):
+    """The expert layer: (out, stats)."""
+    if cfg.router == "sigmoid":
+        return moe_mod.moe_dropless(cfg, p, h, bias)
+    m, aux = moe_mod.moe_forward(cfg, p, h)
+    return m, {"aux": aux}
 
 
 def _remat(cfg, fn):
@@ -152,8 +199,27 @@ def _rope_for(cfg: ModelConfig, positions):
     return rope_cos_sin(positions, dim, cfg.rope_theta)
 
 
-def forward(cfg: ModelConfig, params, tokens, positions=None):
+def _segments(cfg: ModelConfig, params, buffers):
+    """Each scanned stack of layers in order, with its windows and router
+    biases: the leading dense layers, if any, then the rest."""
+    windows = layer_windows(cfg)
+    bias = None if buffers is None else buffers["router_bias"]
+    if "dense_layers" not in params:
+        return [(params["layers"], windows, bias)]
+    n = cfg.first_dense
+    return [(params["dense_layers"], windows[:n], None),
+            (params["layers"], windows[n:], bias)]
+
+
+def forward(cfg: ModelConfig, params, tokens, positions=None, buffers=None):
     """Train-path logits.  tokens: (B,S) int32.  Returns (logits_f32, aux)."""
+    logits, stats = _forward(cfg, params, tokens, positions, buffers)
+    return logits, jnp.sum(stats["aux"])
+
+
+def _forward(cfg: ModelConfig, params, tokens, positions, buffers):
+    """(logits, per-layer stats of the last stack); the leading dense
+    layers add no balance loss."""
     B, S = tokens.shape[-2:] if tokens.ndim >= 2 else (1, tokens.shape[0])
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -163,32 +229,38 @@ def forward(cfg: ModelConfig, params, tokens, positions=None):
     if cfg.pos == "learned":
         x = x + params["pos_table"][:S][None].astype(x.dtype)
     rope = _rope_for(cfg, positions)
-    windows = layer_windows(cfg)
 
     def body(carry, xs):
-        pl, win = xs
-        y, aux, _ = _block(cfg, pl, carry, rope, win)
-        return y, aux
+        pl, win, bias = xs
+        y, stats, _ = _block(cfg, pl, carry, rope, win, bias)
+        return y, stats
 
-    x, auxs = jax.lax.scan(_remat(cfg, body), x, (params["layers"], windows))
+    for layers, windows, bias in _segments(cfg, params, buffers):
+        x, stats = jax.lax.scan(_remat(cfg, body), x, (layers, windows, bias))
     with jax.named_scope("loss"):       # the head exists for the loss
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         un = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
         logits = (x @ un.astype(x.dtype)).astype(jnp.float32)
-    return logits, jnp.sum(auxs)
+    return logits, stats
 
 
-def loss_fn(cfg: ModelConfig, params, batch, *, aux_weight=0.01):
-    """Next-token cross-entropy.  batch: {tokens: (B,S)}."""
+def loss_fn(cfg: ModelConfig, params, batch, buffers=None):
+    """Next-token cross-entropy plus ``aux_weight`` times the balance loss.
+    batch: {tokens: (B,S)}.  A dropless expert model's metrics also carry
+    the per-expert ``load`` of each expert layer and ``moe_counters``."""
     tokens = batch["tokens"]
-    logits, aux = forward(cfg, params, tokens, batch.get("positions"))
+    logits, stats = _forward(cfg, params, tokens, batch.get("positions"), buffers)
+    aux = jnp.sum(stats["aux"])
     with jax.named_scope("loss"):
         tgt = tokens[:, 1:]
         lg = logits[:, :-1]
         lse = jax.nn.logsumexp(lg, axis=-1)
         ll = jnp.take_along_axis(lg, tgt[..., None], axis=-1)[..., 0]
         loss = jnp.mean(lse - ll)
-        return loss + aux_weight * aux, {"ce": loss, "aux": aux}
+        metrics = {"ce": loss, "aux": aux}
+        if "load" in stats:
+            metrics.update(moe_counters(cfg, stats), load=stats["load"])
+        return loss + cfg.aux_weight * aux, metrics
 
 
 # ------------------------------------------------------------------ serving
@@ -213,7 +285,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
     return c
 
 
-def prefill(cfg: ModelConfig, params, tokens, max_len: int, positions=None):
+def prefill(cfg: ModelConfig, params, tokens, max_len: int, positions=None,
+            buffers=None):
     """Run the full prompt, return (last_logits, cache)."""
     B, S = tokens.shape
     if positions is None:
@@ -224,14 +297,18 @@ def prefill(cfg: ModelConfig, params, tokens, max_len: int, positions=None):
     if cfg.pos == "learned":
         x = x + params["pos_table"][:S][None].astype(x.dtype)
     rope = _rope_for(cfg, positions)
-    windows = layer_windows(cfg)
 
     def body(carry, xs):
-        pl, win = xs
-        y, _aux, kv = _block(cfg, pl, carry, rope, win, return_kv=True)
+        pl, win, bias = xs
+        y, _stats, kv = _block(cfg, pl, carry, rope, win, bias, return_kv=True)
         return y, kv
 
-    x, kvs = jax.lax.scan(body, x, (params["layers"], windows))
+    parts = []
+    for layers, windows, bias in _segments(cfg, params, buffers):
+        x, kvs = jax.lax.scan(body, x, (layers, windows, bias))
+        parts.append(kvs)
+    if len(parts) > 1:                  # caches stack all layers in order
+        kvs = jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
     cache = init_cache(cfg, B, max_len)
     cache["pos"] = jnp.int32(S)
     if cfg.family == "ssm":
@@ -259,7 +336,7 @@ def prefill(cfg: ModelConfig, params, tokens, max_len: int, positions=None):
     return logits, cache
 
 
-def _block_decode(cfg: ModelConfig, pl, x, rope, window, caches, pos):
+def _block_decode(cfg: ModelConfig, pl, x, rope, window, caches, pos, bias=None):
     """One block, one token.  ``caches``: per-layer slice tuple."""
     new = []
     if cfg.family == "ssm":
@@ -283,8 +360,8 @@ def _block_decode(cfg: ModelConfig, pl, x, rope, window, caches, pos):
     else:
         x = x + a
     h2 = rmsnorm(x, pl["norm2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        m, _ = moe_mod.moe_forward(cfg, pl["moe"], h2)
+    if "moe" in pl:
+        m, _ = _moe(cfg, pl["moe"], h2, bias)
         if cfg.dense_residual:
             m = m + swiglu(h2, pl["mlp"]["wg"].astype(x.dtype),
                            pl["mlp"]["wu"].astype(x.dtype),
@@ -306,7 +383,7 @@ def _cache_keys(cfg: ModelConfig):
     return keys
 
 
-def decode_step(cfg: ModelConfig, params, cache, tokens):
+def decode_step(cfg: ModelConfig, params, cache, tokens, buffers=None):
     """One serving step.  tokens: (B, 1) int32; returns (logits, cache)."""
     B = tokens.shape[0]
     pos = cache["pos"]
@@ -318,19 +395,25 @@ def decode_step(cfg: ModelConfig, params, cache, tokens):
     if cfg.pos == "learned":
         x = x + jax.lax.dynamic_slice_in_dim(params["pos_table"], pos, 1)[None].astype(x.dtype)
     rope = _rope_for(cfg, positions)
-    windows = layer_windows(cfg)
     keys = _cache_keys(cfg)
 
     def body(carry, xs):
-        pl, win = xs[0], xs[1]
-        caches = xs[2:]
-        y, new = _block_decode(cfg, pl, carry, rope, win, caches, pos)
+        pl, win, bias = xs[:3]
+        caches = xs[3:]
+        y, new = _block_decode(cfg, pl, carry, rope, win, caches, pos, bias)
         return y, new
 
-    x, new = jax.lax.scan(body, x, (params["layers"], windows,
-                                    *[cache[k] for k in keys]))
-    for k, v in zip(keys, new):
-        cache[k] = v
+    segments = _segments(cfg, params, buffers)
+    parts, start = [], 0
+    for layers, windows, bias in segments:
+        n = windows.shape[0]
+        caches = [cache[k] if len(segments) == 1 else cache[k][start:start + n]
+                  for k in keys]
+        x, new = jax.lax.scan(body, x, (layers, windows, bias, *caches))
+        parts.append(new)
+        start += n
+    for i, k in enumerate(keys):
+        cache[k] = jnp.concatenate([p[i] for p in parts]) if len(parts) > 1 else parts[0][i]
     cache["pos"] = pos + 1
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     un = (params["embed"].T if cfg.tie_embeddings else params["unembed"])

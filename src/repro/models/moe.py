@@ -14,6 +14,13 @@ Design notes (expert parallelism on the ``model`` mesh axis):
 
 Returns the layer output plus the load-balancing auxiliary loss
 (Switch-style: E · Σ_e f_e · p_e).
+
+``moe_dropless`` is the DeepSeek-V3 expert layer (``router="sigmoid"``):
+it routes over all ``n_experts``, computes the part of the result that the
+experts held here give (``experts_held`` from ``expert_first``; the other
+experts' part is computed on the chips that hold them), and drops nothing:
+the (token, choice) rows of the held experts are sorted by expert and go
+through one grouped matmul (``jax.lax.ragged_dot``) per projection.
 """
 from __future__ import annotations
 
@@ -21,10 +28,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ModelConfig
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, swiglu
 
 
 def moe_init(cfg: ModelConfig, key):
+    if cfg.router == "sigmoid":
+        return held_moe_init(cfg, key)
     d, E, f = cfg.d_model, cfg.n_experts, cfg.d_expert
     ks = jax.random.split(key, 4)
     return {
@@ -195,3 +204,98 @@ def _moe_gather(cfg: ModelConfig, p, x):
     y = (y.reshape(G, Tg, K, d) * w[..., None].astype(y.dtype)).sum(2)
     y = y.reshape(G * Tg, d)[:T].reshape(B, S, d)
     return y, aux
+
+
+# ================================================ DeepSeek-V3, dropless
+
+def held_moe_init(cfg: ModelConfig, key):
+    """The router over all ``n_experts``; the weights of the held experts,
+    each drawn from its own key, so that a share holds what the uncut layer
+    holds for those experts; the shared experts as one SwiGLU."""
+    d, E, f = cfg.d_model, cfg.n_experts, cfg.d_expert
+    ks = jax.random.split(key, 5)
+    mine = slice(cfg.expert_first, cfg.expert_first + cfg.held)
+
+    def experts(k, shape, fan_in):
+        return jax.vmap(lambda ke: dense_init(ke, shape, fan_in, cfg.pdt))(
+            jax.random.split(k, E)[mine])
+
+    p = {"router": dense_init(ks[0], (d, E), d, jnp.float32),
+         "wg": experts(ks[1], (d, f), d), "wu": experts(ks[2], (d, f), d),
+         "wd": experts(ks[3], (f, d), f)}
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        k1, k2, k3 = jax.random.split(ks[4], 3)
+        p["shared"] = {"wg": dense_init(k1, (d, fs), d, cfg.pdt),
+                       "wu": dense_init(k2, (d, fs), d, cfg.pdt),
+                       "wd": dense_init(k3, (fs, d), fs, cfg.pdt)}
+    return p
+
+
+def route_sigmoid(cfg: ModelConfig, router, bias, x):
+    """DeepSeek-V3's router (``noaux_tc`` with one group) over x: (B, S, d).
+
+    Float32 sigmoid scores; the top-k of score + bias are chosen (the bias
+    steers selection only); their weights are the unbiased scores,
+    normalised over the k and times ``routed_scale``.  Returns the weights
+    and expert ids (B*S, k), each expert's load (tokens that chose it, (E,))
+    and the sequence-wise balance loss, mean over the batch's sequences of
+    sum_i f_i P_i (arXiv:2412.19437 eqs. 17-20)."""
+    B, S, d = x.shape
+    E, K = cfg.n_experts, cfg.top_k
+    s = jax.nn.sigmoid(jnp.dot(x.reshape(B * S, d).astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))  # (T, E)
+    sel = s if bias is None else s + jax.lax.stop_gradient(bias)
+    _, idx = jax.lax.top_k(sel, K)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    chosen = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(1)       # (T, E)
+    f = chosen.reshape(B, S, E).sum(1) * (E / (K * S))
+    P = (s / s.sum(-1, keepdims=True)).reshape(B, S, E).mean(1)
+    aux = jnp.mean(jnp.sum(f * P, -1))
+    return w, idx, chosen.sum(0), aux
+
+
+def moe_dropless(cfg: ModelConfig, p, x, bias=None):
+    """x: (B, S, d) -> (out, stats).  ``stats``: the balance loss ``aux``,
+    the load of every expert ``load`` (E,), ``rows``, the rows the held
+    experts' grouped matmuls were given, and ``dropped``, the (token,
+    choice) pairs routed to a held expert whose output row reached the
+    combine all zero."""
+    B, S, d = x.shape
+    T, K, Eh = B * S, cfg.top_k, cfg.held
+    dt = x.dtype
+    xt = x.reshape(T, d)
+    with jax.named_scope("router"):
+        w, idx, load, aux = route_sigmoid(cfg, p["router"], bias, x)
+    with jax.named_scope("dispatch"):
+        e = idx.reshape(T * K) - cfg.expert_first
+        held = (e >= 0) & (e < Eh)
+        e = jnp.where(held, e, Eh)               # other chips' experts sort last
+        order = jnp.argsort(e, stable=True)
+        sizes = jnp.zeros((Eh + 1,), jnp.int32).at[e].add(1)[:Eh]
+        # a grouped matmul leaves the rows past its groups undefined (the
+        # TPU's ragged_dot does not write them), on the way forward and
+        # back: zero them at every product's input and output
+        live = (jnp.arange(T * K) < sizes.sum())[:, None]
+        rows = jnp.repeat(xt, K, axis=0).at[order].get(unique_indices=True)
+        rows = jnp.where(live, rows, 0)
+    with jax.named_scope("experts"):
+        def gmm(a, wt):
+            return jnp.where(live, jax.lax.ragged_dot(a, wt.astype(dt), sizes), 0)
+        h = jax.nn.silu(gmm(rows, p["wg"])) * gmm(rows, p["wu"])
+        out = gmm(h, p["wd"])
+    with jax.named_scope("combine"):
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * K, dtype=order.dtype))
+        y = out.at[inv].get(unique_indices=True).reshape(T, K, d)
+        # a held (token, choice) whose row came back all zero was lost on
+        # the way: at dispatch, in the grouped matmul or in the unsort
+        dropped = jnp.sum(held.reshape(T, K) & ~jnp.any(y != 0, axis=-1))
+        y = (y * w[..., None].astype(dt)).sum(1)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            sh = p["shared"]
+            y = y + swiglu(xt, sh["wg"].astype(dt), sh["wu"].astype(dt), sh["wd"].astype(dt))
+    return y.reshape(B, S, d), {"aux": aux, "load": load, "rows": sizes.sum(),
+                                "dropped": dropped}

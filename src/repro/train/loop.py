@@ -23,6 +23,10 @@ from repro.optim.adamw import AdamW
 from repro.train import steps as tsteps
 
 
+# the step's counters that the step span carries (a dropless expert model's)
+STEP_COUNTERS = ("moe_rows", "moe_load_max", "moe_dropped")
+
+
 class MetricsLog:
     """JSONL metrics through the FS (another 'legacy' NVCache consumer)."""
 
@@ -104,11 +108,14 @@ def train(model: Model, optimizer: AdamW, pipeline, fs, *,
                 batch = pipeline.next()
         if batch is None:
             break
-        with obs.span("train.step_us", step=step):
+        with obs.span("train.step_us", step=step) as sp:
             t0 = time.perf_counter()
             state, metrics = step_fn(state, batch)
             jax.block_until_ready(metrics["loss"])
             metrics = dict(metrics, step_time=time.perf_counter() - t0)
+            counters = {k: int(metrics[k]) for k in STEP_COUNTERS if k in metrics}
+            if counters:
+                sp.set(**counters)
         metrics_log.log(step, metrics)
         history.append({"step": step, **{k: float(v) for k, v in metrics.items()}})
         if heartbeat:

@@ -29,8 +29,14 @@ def bind_mesh(fn, mesh):
 
 
 def init_train_state(model: Model, optimizer: AdamW, key):
+    """{params, opt}, and ``buffers`` for a model that has state outside
+    the optimizer (a DeepSeek-V3 router's bias)."""
     params = model.init(key)
-    return {"params": params, "opt": optimizer.init(params)}
+    state = {"params": params, "opt": optimizer.init(params)}
+    buffers = model.init_buffers()
+    if buffers is not None:
+        state["buffers"] = buffers
+    return state
 
 
 def abstract_train_state(model: Model, optimizer: AdamW):
@@ -40,15 +46,19 @@ def abstract_train_state(model: Model, optimizer: AdamW):
 
 def make_train_step(model: Model, optimizer: AdamW, *, compress: bool = False):
     def step(state, batch):
+        buffers = state.get("buffers")
         (loss, metrics), grads = jax.value_and_grad(
-            model.loss, has_aux=True)(state["params"], batch)
+            model.loss, has_aux=True)(state["params"], batch, buffers)
         with jax.named_scope("optimizer"):
             if compress:
                 grads = grad_compress.compress_tree(grads)
             updates, opt, om = optimizer.update(grads, state["opt"], state["params"])
             params = apply_updates(state["params"], updates)
+            new = {"params": params, "opt": opt}
+            if buffers is not None:
+                new["buffers"] = model.update_buffers(buffers, metrics.pop("load"))
         metrics = dict(metrics, loss=loss, **om)
-        return {"params": params, "opt": opt}, metrics
+        return new, metrics
 
     return step
 
@@ -75,6 +85,8 @@ def train_shardings(model: Model, optimizer: AdamW, mesh, batch_spec_like,
     mspec = shd.param_specs(state["opt"]["m"], mesh, fsdp=fsdp)
     state_spec = {"params": pspec,
                   "opt": {"m": mspec, "v": mspec, "step": shd.P()}}
+    if "buffers" in state:
+        state_spec["buffers"] = jax.tree.map(lambda _: shd.P(), state["buffers"])
     bspec = shd.batch_specs(batch_spec_like, mesh)
     metrics_spec = None     # replicated scalars
     return (shd.named(mesh, state_spec), shd.named(mesh, bspec)), \

@@ -1,5 +1,7 @@
 """Per-architecture smoke tests: reduced config of the same family, one
 forward/train step + prefill + decode on CPU; shapes and finiteness."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -42,10 +44,16 @@ def test_smoke_prefill_then_decode(arch):
 
 
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "hymba-1.5b", "mamba2-780m",
-                                  "minicpm3-4b"])
+                                  "minicpm3-4b", "moonlight-16b-a3b"])
 def test_decode_matches_forward(arch):
     """Teacher-forced decode must reproduce the train-path logits."""
     cfg = get_smoke(arch)
+    tol = 2e-1
+    if cfg.family == "moe":
+        # a top-k router flips its choice near a tie under bfloat16
+        # rounding, which moves a token's logits by far more than rounding:
+        # compare in float32, where the two paths differ by rounding alone
+        cfg, tol = dataclasses.replace(cfg, compute_dtype="float32"), 1e-4
     model = build(cfg)
     params = model.init(KEY)
     S = 16
@@ -59,7 +67,7 @@ def test_decode_matches_forward(arch):
     dec = jnp.stack(outs, axis=1)                      # logits at positions 1..S-1
     ref = full_logits[:, 1:S]
     err = jnp.max(jnp.abs(dec - ref))
-    assert float(err) < 2e-1, f"{arch}: decode/forward divergence {float(err)}"
+    assert float(err) < tol, f"{arch}: decode/forward divergence {float(err)}"
 
 
 @pytest.mark.parametrize("arch", all_archs())
@@ -77,6 +85,7 @@ def test_full_config_consistency(arch):
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
         "qwen2-vl-7b": (28, 3584, 28, 4, 18944, 152064),
         "mamba2-780m": (48, 1536, 0, 0, 0, 50280),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
            cfg.vocab)
@@ -88,6 +97,11 @@ def test_full_config_consistency(arch):
     if arch.startswith("qwen3") or arch.startswith("arctic"):
         assert cfg.n_experts == 128
         assert cfg.top_k == (8 if arch.startswith("qwen3") else 2)
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.n_experts, cfg.top_k, cfg.d_expert, cfg.n_shared_experts) == (64, 6, 1408, 2)
+        assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                cfg.v_head_dim) == (None, 512, 128, 64, 128)
+        assert (cfg.first_dense, cfg.held, cfg.router, cfg.routed_scale) == (1, 64, "sigmoid", 2.446)
 
 
 def test_param_counts_plausible():
@@ -98,6 +112,12 @@ def test_param_counts_plausible():
     a = get_config("qwen3-moe-30b-a3b")
     assert 25e9 < a.param_count() < 36e9
     assert 2e9 < a.active_param_count() < 5e9
+    # 27 layers at the published widths: 13.76 M of attention each, a dense
+    # SwiGLU of 69.21 M, and 26 expert layers of 64 experts (3*2048*1408
+    # each), a shared SwiGLU of 2816 and the router; embedding and head
+    m = get_config("moonlight-16b-a3b")
+    assert abs(m.param_count() / 15.96e9 - 1) < 0.05
+    assert 2.5e9 < m.active_param_count() < 3.5e9          # "A3B"
 
 
 def test_long_500k_applicability():
