@@ -67,7 +67,8 @@ class CheckpointManager:
             w = codec.Writer(self.fs, path, encoding=self.encoding,
                              close_on_finish=False)
             flat, _ = _flatten(tree)
-            sp.set(bytes=sum(getattr(leaf, "nbytes", 0) for _, leaf in flat))
+            sp.set(bytes=sum(getattr(leaf, "nbytes", 0) for _, leaf in flat),
+                   encode_workers=w.workers)
             for key, leaf in flat:
                 w.put_leaf(key, leaf)
             info = w.finish()

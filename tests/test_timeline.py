@@ -14,6 +14,7 @@ import jax
 import pytest
 from jax.profiler import ProfileData
 
+from repro.checkpoint import codec
 from repro.configs.registry import get_smoke
 from repro.core import NVCache, Policy
 from repro.data.pipeline import SyntheticTokens
@@ -28,10 +29,10 @@ from repro.train.loop import train
 PREFIXES = ("train.", "ckpt.", "nv.", "log.", "drain.")
 TRAIN_SPANS = {"train.step_us", "train.batch_us", "train.metrics_us",
                "train.save_us", "train.d2h_us", "ckpt.save_us",
-               "ckpt.finalize_us", "ckpt.encode_us", "ckpt.write_us",
-               "ckpt.manifest_us", "train.pipeline_us", "train.restore_us",
-               "ckpt.restore_us", "ckpt.read_us", "ckpt.decode_us",
-               "train.h2d_us", "nv.recover_us"}
+               "ckpt.finalize_us", "ckpt.encode_us", "ckpt.encode_wait_us",
+               "ckpt.write_us", "ckpt.manifest_us", "train.pipeline_us",
+               "train.restore_us", "ckpt.restore_us", "ckpt.read_us",
+               "ckpt.decode_us", "train.h2d_us", "nv.recover_us"}
 
 
 def _trace(logdir):
@@ -62,6 +63,11 @@ def _inside(inner, outer):
 
 def _within(spans, name, outer):
     return [s for s in spans if s[0] == name and _inside(s, outer)]
+
+
+def _during(spans, name, outer):
+    """The ``name`` spans of any thread that lie inside ``outer``'s time."""
+    return [s for s in spans if s[0] == name and outer[1] <= s[1] and s[2] <= outer[2]]
 
 
 @pytest.fixture(scope="module")
@@ -107,7 +113,7 @@ def test_save_spans_nest_and_count_the_state(traced_job):
         assert len(ckpt) == 1 and ckpt[0][4]["bytes"] == nbytes
         assert len(_within(spans, "train.d2h_us", outer)) == 1
         assert len(_within(spans, "train.pipeline_us", outer)) == 1
-        enc = _within(spans, "ckpt.encode_us", ckpt[0])
+        enc = _during(spans, "ckpt.encode_us", ckpt[0])
         assert enc and sum(s[4]["bytes"] for s in enc) == nbytes
         assert all(0 < s[4]["out_bytes"] for s in enc)
         assert len(_within(spans, "ckpt.manifest_us", ckpt[0])) == 1
@@ -115,9 +121,29 @@ def test_save_spans_nest_and_count_the_state(traced_job):
         step = outer[4]["step"]
         if step in sizes:
             assert sum(s[4]["bytes"] for s in writes) == sizes[step]
-    assert all(any(_inside(s, o) for o in spans if o[0] == "ckpt.save_us")
-               for s in spans if s[0] in ("ckpt.encode_us", "ckpt.write_us",
+    ckpt_saves = [s for s in spans if s[0] == "ckpt.save_us"]
+    assert all(any(_inside(s, o) for o in ckpt_saves)
+               for s in spans if s[0] in ("ckpt.encode_wait_us", "ckpt.write_us",
                                           "ckpt.manifest_us"))
+    assert all(any(o[1] <= s[1] and s[2] <= o[2] for o in ckpt_saves)
+               for s in spans if s[0] == "ckpt.encode_us")
+
+
+def test_save_encodes_on_pool_threads(traced_job):
+    """Each save's records are encoded on the codec's pool threads
+    (``ckpt.encode_us``) while the calling thread waits for each in turn
+    (``ckpt.encode_wait_us``) and writes it; ``ckpt.save_us`` carries the
+    pool's size."""
+    spans, _, _, _ = traced_job
+    for ckpt in [s for s in spans if s[0] == "ckpt.save_us"]:
+        enc = _during(spans, "ckpt.encode_us", ckpt)
+        waits = _within(spans, "ckpt.encode_wait_us", ckpt)
+        # every write but the magic, the index and the footer is a record
+        assert len(waits) == len(enc) == len(_within(spans, "ckpt.write_us", ckpt)) - 3
+        threads = {s[3] for s in enc}
+        assert ckpt[3] not in threads
+        assert ckpt[4]["encode_workers"] == codec.encode_workers()
+        assert 1 <= len(threads) <= ckpt[4]["encode_workers"]
 
 
 def test_restore_spans_nest_and_count_the_tree(traced_job):
