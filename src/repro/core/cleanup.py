@@ -224,7 +224,7 @@ class CleanupThread(threading.Thread):
                                     if fp.direct))
             # phase 2: extent writes under page cleanup locks + index retire
             t0 = time.perf_counter_ns() if lv2 else 0
-            drained = _drain.apply_plan(plan, pol, abort=self._abort, stats=self)
+            drained = _drain.apply_plan(plan, abort=self._abort, stats=self)
             if lv2:
                 obs.prof.h_drain_apply.record_ns(time.perf_counter_ns() - t0)
             if drained is None:
@@ -288,8 +288,6 @@ class CleanupThread(threading.Thread):
         blocked on recycling — the carry must never extend a log-full
         stall)."""
         pol = self.log.policy
-        if not (pol.drain_coalesce and pol.coalesce_span_batches):
-            return 0
         if (self.drain_event.is_set() or self.stop_event.is_set()
                 or self.hard_stop.is_set()):
             return 0
@@ -483,8 +481,7 @@ class CleanupPool:
                  pager=None, writeback: Optional[Callable] = None,
                  obs=None):
         self.log = log
-        self.fsync_scheduler = FsyncEpochScheduler(
-            enabled=log.policy.fsync_epoch)
+        self.fsync_scheduler = FsyncEpochScheduler()
         self.threads = [CleanupThread(log, sh, resolve_file,
                                       fsync_scheduler=self.fsync_scheduler,
                                       meta_gate=meta_gate, reap=reap,

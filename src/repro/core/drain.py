@@ -132,12 +132,11 @@ class _PageImage:
 
 
 class _FileAcc:
-    __slots__ = ("file", "ents", "raw", "entries", "nbytes")
+    __slots__ = ("file", "ents", "entries", "nbytes")
 
     def __init__(self, file):
         self.file = file
         self.ents: List[tuple] = []     # (off, end, data, idx), log order
-        self.raw: List[tuple] = []      # legacy mode: (off, bytes, idx)
         self.entries = 0
         self.nbytes = 0
 
@@ -241,25 +240,19 @@ def build_plan(shard: LogShard, start: int, run: int,
         acc.nbytes += e.length
         if e.length == 0:
             continue
-        if not policy.drain_coalesce:
-            acc.raw.append((e.off, bytes(e.data), e.idx))
-            continue
         acc.ents.append((e.off, e.off + e.length, e.data, e.idx))
 
     for acc in order:
         fp = FilePlan(acc.file)
         fp.entries = acc.entries
         fp.nbytes = acc.nbytes
-        if not policy.drain_coalesce:
-            fp.extents = _raw_extents(acc, ps)
-        else:
-            ents = sorted(acc.ents, key=itemgetter(0))
-            fp.direct = _disjoint(ents)
-            fp.extents = (
-                _direct_extents(ents, ps, policy.coalesce_max_extent)
-                if fp.direct else
-                _coalesced_extents(_page_images(acc.ents, ps), ps,
-                                   policy.coalesce_max_extent))
+        ents = sorted(acc.ents, key=itemgetter(0))
+        fp.direct = _disjoint(ents)
+        fp.extents = (
+            _direct_extents(ents, ps, policy.coalesce_max_extent)
+            if fp.direct else
+            _coalesced_extents(_page_images(acc.ents, ps), ps,
+                               policy.coalesce_max_extent))
         plan.files.append(fp)
     return plan
 
@@ -333,18 +326,6 @@ def _page_images(ents: List[tuple], ps: int) -> Dict[int, _PageImage]:
     return pages
 
 
-def _raw_extents(acc: _FileAcc, ps: int) -> List[Extent]:
-    """Entry-at-a-time degenerate plan (``drain_coalesce=False``): one
-    extent per log entry, exactly the paper's per-entry forwarding — kept
-    as the measurable baseline for the coalescing win."""
-    out = []
-    for off, data, idx in acc.raw:
-        pages = list(range(off // ps, (off + max(len(data), 1) - 1) // ps + 1))
-        out.append(Extent(off, bytearray(data), pages,
-                          {p: [idx] for p in pages}))
-    return out
-
-
 def _coalesced_extents(pages: Dict[int, _PageImage], ps: int,
                        max_extent: int) -> List[Extent]:
     """Flatten materialized page images into maximal contiguous extents."""
@@ -385,32 +366,25 @@ def _coalesced_extents(pages: Dict[int, _PageImage], ps: int,
     return out
 
 
-def apply_plan(plan: DrainPlan, policy: Policy, *,
-               abort: Optional[AbortFn] = None,
+def apply_plan(plan: DrainPlan, *, abort: Optional[AbortFn] = None,
                stats=None) -> Optional[Dict[object, int]]:
     """Phase 2: issue the extent writes and retire the dirty-page index.
 
     Per file: take the cleanup locks of every covered page (ascending — the
     same total order the write path uses, and drain threads of different
-    shards never share a page, so there is no cycle), issue one vectored
-    ``pwritev`` when the backend supports it (else per-extent ``pwrite``),
-    then drop the batch's refs from each covered page.  Returns
-    ``{file: entries_drained}``, or ``None`` on abort — in which case the
-    log is *not* consumed and recovery replays everything (idempotent).
+    shards never share a page, so there is no cycle), issue the extents as
+    vectored ``pwritev`` calls, then drop the batch's refs from each covered
+    page.  Returns ``{file: entries_drained}``, or ``None`` on abort — in
+    which case the log is *not* consumed and recovery replays everything
+    (idempotent).
     """
     drained: Dict[object, int] = {}
     for fp in plan.files:
         if abort is not None and abort(APPLY_FILE):
             return None
-        f = fp.file
-        pwritev = getattr(f.backend, "pwritev", None)
-        if policy.drain_coalesce and pwritev is not None:
-            ok = _apply_vectored(plan, fp, pwritev, abort, stats)
-        else:
-            ok = _apply_serial(plan, fp, abort, stats)
-        if not ok:
+        if not _apply_vectored(plan, fp, abort, stats):
             return None
-        drained[f] = fp.entries
+        drained[fp.file] = fp.entries
     return drained
 
 
@@ -448,9 +422,10 @@ def _chunk_retire(chunk: List[Extent]) -> Dict[int, List[int]]:
     return out
 
 
-def _apply_vectored(plan, fp, pwritev, abort, stats) -> bool:
+def _apply_vectored(plan, fp, abort, stats) -> bool:
     """A file's extents in chunks: one lock hold + one pwritev per chunk,
     then one retire pass per page descriptor."""
+    pwritev = fp.file.backend.pwritev
     obs = getattr(stats, "obs", None)
     lv2 = obs is not None and obs.prof.lv2
     for i in range(0, len(fp.extents), VEC_CHUNK):
@@ -474,34 +449,6 @@ def _apply_vectored(plan, fp, pwritev, abort, stats) -> bool:
                 # a short list beats building a set for the membership test
                 d.retire_refs(plan.sid,
                               idxs if len(idxs) <= 8 else set(idxs))
-        finally:
-            for _p, d in reversed(descs):
-                d.cleanup_lock.release()
-    return True
-
-
-def _apply_serial(plan, fp, abort, stats) -> bool:
-    """Per-extent pwrite + retire (legacy mode, or backend without pwritev)."""
-    obs = getattr(stats, "obs", None)
-    lv2 = obs is not None and obs.prof.lv2
-    for ext in fp.extents:
-        if abort is not None and abort(APPLY_EXTENT):
-            return False
-        descs = _lock_descs(fp.file, ext.pages)
-        try:
-            t0 = time.perf_counter_ns() if lv2 else 0
-            fp.file.backend.pwrite(bytes(ext.data), ext.off)
-            if lv2:
-                obs.prof.h_drain_pwritev.record_ns(
-                    time.perf_counter_ns() - t0)
-            if stats is not None:
-                stats.stats_extents += 1
-            if abort is not None and abort(APPLY_RETIRE):
-                return False
-            for p, d in descs:
-                idxs = ext.retire.get(p)
-                if idxs:
-                    d.retire_refs(plan.sid, set(idxs))
         finally:
             for _p, d in reversed(descs):
                 d.cleanup_lock.release()
@@ -550,8 +497,7 @@ class FsyncEpochScheduler:
         "stats_requests": "_lock", "stats_issued": "_lock",
     }
 
-    def __init__(self, enabled: bool = True):
-        self.enabled = enabled
+    def __init__(self):
         self._lock = locking.make_lock("leaf:fsync_sched")
         self._state: Dict[int, _SyncState] = {}   # id(backend) -> state
         #                                           guarded-by: _lock
@@ -570,12 +516,6 @@ class FsyncEpochScheduler:
             return self.stats_issued
 
     def fsync(self, backend) -> None:
-        if not self.enabled:
-            with self._lock:
-                self.stats_requests += 1
-                self.stats_issued += 1
-            backend.fsync()
-            return
         key = id(backend)
         with self._lock:
             self.stats_requests += 1

@@ -85,11 +85,10 @@ class Policy:
     shard_route: str = "stripe"        # "stripe" | "fdid" (see module docstring)
     stripe_pages: int = 64             # stripe width, in read-cache pages
     # drain engine (beyond paper; cf. dm-writeboost's coalesced submission):
-    drain_coalesce: bool = True        # plan/apply page+extent coalescing;
-    #                                    False == the paper's entry-at-a-time
+    # each batch is planned into page-coalesced extents and applied with
+    # vectored writes, and concurrent per-shard fsyncs of one backend file
+    # merge into epochs (repro.core.drain).
     coalesce_max_extent: int = MIB     # max bytes per coalesced extent write
-    fsync_epoch: bool = True           # merge concurrent per-shard fsyncs of
-    #                                    the same backend file into epochs
     # batch-spanning coalescing (cf. NVLog keeping its tail extent open
     # across syncs): a drain batch may leave its contiguous tail extent
     # (capped at one page-span of bytes) unconsumed so the next batch's
@@ -97,8 +96,6 @@ class Policy:
     # entries stay committed in the log with their dirty-page-index refs
     # live, and are force-flushed once they are older than the deadline or
     # whenever a drain barrier (close/flush/fsync) is requested.
-    coalesce_span_batches: bool = True  # carry the open tail extent across
-    #                                     batches (requires drain_coalesce)
     coalesce_deadline_ms: float = 5.0   # max age of a carried tail extent
     # read path (the read-side twin of the drain engine, paper Fig. 2 miss
     # procedure generalized from one page to one aligned extent): a cache
@@ -297,24 +294,6 @@ class Policy:
     def nvmm_bytes(self) -> int:
         return self.entries_base + self.log_entries * self.entry_size
 
-
-#: Paper §IV-A configuration (64 GiB log, 1 GiB read cache), with the
-#: paper's propagation path: entry-at-a-time draining behind the kernel
-#: page cache, no user-space coalescing or fsync-epoch merging — the
-#: faithful-reproduction baseline the beyond-paper engine is measured
-#: against (benchmarks/fig8_coalescing.py).
-PAPER_DEFAULT = Policy(
-    entry_size=4 * KIB,
-    log_entries=16 * 1024 * 1024,
-    read_cache_pages=250_000,
-    batch_min=1000,
-    batch_max=10000,
-    drain_coalesce=False,
-    fsync_epoch=False,
-    coalesce_span_batches=False,
-    readahead_pages=1,
-    readahead_ramp=False,
-)
 
 class StreamClassifier:
     """Per-file write-stream classifier for the dual persistence engine.

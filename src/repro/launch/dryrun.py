@@ -9,7 +9,7 @@ count on first init); do not move them.
 
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k --mesh single
-  python -m repro.launch.dryrun --all [--mesh both] --out benchmarks/artifacts
+  python -m repro.launch.dryrun --all [--mesh both] --out .scratch/dryrun
 """
 
 import argparse
@@ -154,7 +154,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="single", choices=["single", "multi", "both"])
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--no-fsdp", action="store_true")
-    ap.add_argument("--out", default="benchmarks/artifacts")
+    ap.add_argument("--out", default=".scratch/dryrun")
     args = ap.parse_args(argv)
 
     os.makedirs(args.out, exist_ok=True)

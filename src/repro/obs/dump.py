@@ -2,12 +2,12 @@
 
 Two modes:
 
-* ``python -m repro.obs.dump <image.bin> [--policy test|paper]`` —
-  decode the flight ring out of a raw NVMM image (the byte dump of a
-  region, e.g. ``bytes(nvmm.load(0, nvmm.size))`` written to a file)
-  and print the surviving timeline.  The policy choice must match the
-  image's geometry — the superblock is validated first and a mismatch
-  is reported rather than mis-decoded.
+* ``python -m repro.obs.dump <image.bin>`` — decode the flight ring out
+  of a raw NVMM image (the byte dump of a region, e.g.
+  ``bytes(nvmm.load(0, nvmm.size))`` written to a file) and print the
+  surviving timeline.  The image must have the ``TEST_SMALL`` geometry —
+  the superblock is validated first and a mismatch is reported rather
+  than mis-decoded.
 * ``python -m repro.obs.dump --selftest`` — build a small engine,
   run writes/namespace ops, inject a power loss mid-workload, recover,
   and print the post-crash forensic timeline.  Exit 1 if the recovered
@@ -19,10 +19,8 @@ from __future__ import annotations
 import argparse
 import sys
 
-from repro.core.policy import PAPER_DEFAULT, TEST_SMALL
+from repro.core.policy import TEST_SMALL
 from repro.obs.flight import decode_ring, format_timeline
-
-_POLICIES = {"test": TEST_SMALL, "paper": PAPER_DEFAULT}
 
 
 class _ImageNVMM:
@@ -45,7 +43,7 @@ def dump_image(path: str, policy) -> int:
         buf = fh.read()
     if len(buf) < policy.nvmm_bytes:
         print(f"image is {len(buf)} bytes but the {policy!r} geometry "
-              f"needs {policy.nvmm_bytes} — wrong --policy?",
+              f"needs {policy.nvmm_bytes} — another geometry?",
               file=sys.stderr)
         return 1
     nvmm = _ImageNVMM(buf)
@@ -110,8 +108,6 @@ def main(argv=None) -> int:
         prog="python -m repro.obs.dump",
         description="decode an NVMM flight-recorder ring")
     ap.add_argument("image", nargs="?", help="raw NVMM region image file")
-    ap.add_argument("--policy", choices=sorted(_POLICIES), default="test",
-                    help="geometry of the image (default: test)")
     ap.add_argument("--selftest", action="store_true",
                     help="crash-inject a small engine and dump its ring")
     args = ap.parse_args(argv)
@@ -119,7 +115,7 @@ def main(argv=None) -> int:
         return selftest()
     if not args.image:
         ap.error("an image file (or --selftest) is required")
-    return dump_image(args.image, _POLICIES[args.policy])
+    return dump_image(args.image, TEST_SMALL)
 
 
 if __name__ == "__main__":

@@ -7,8 +7,7 @@ synchronous durability is not their data plane — it is their **metadata
 protocols**: every one of them turns a multi-write transaction into an
 atomic event through a namespace operation the kernel promises to be
 atomic.  These models reproduce exactly those protocols, small enough to
-fuse-crash at every step (tests/test_legacy_crash.py) and fast enough to
-benchmark (benchmarks/fig3_dbbench.py):
+fuse-crash at every step (tests/test_legacy_crash.py):
 
 * :class:`SQLiteRollbackDB` — rollback-journal mode (SQLite's default
   ``journal_mode=DELETE``): before touching the database, the *original*

@@ -119,6 +119,42 @@ def test_paged_write_read_flush_roundtrip():
     nv.shutdown()
 
 
+def test_paged_mode_persists_fewer_bytes_per_committed_byte():
+    """Whole-page rewrites at eager drain settings (``batch_min=1``): the
+    log persists each overwrite twice (its NVMM entry, then the drain's
+    backend write), a frame once in place, with one backend image at
+    writeback.  Counting NVMM plus backend bytes, paged mode persists at
+    least 1.5x fewer bytes per committed byte.  ``batch_max`` is half a
+    pass, so no batch can coalesce one pass's overwrite with the next."""
+    page, n_pages, passes = 4096, 32, 8
+    persisted = {}
+    for mode in ("log", "paged"):
+        pol = Policy(entry_size=page, log_entries=256, page_size=page,
+                     batch_min=1, batch_max=n_pages // 2,
+                     page_frames=2 * n_pages if mode == "paged" else 0,
+                     classify_window=8)
+        tier = Tier(DRAM)
+        nv = NVCache(pol, tier)
+        fd = nv.open("/hot.dat")
+        for p in range(n_pages):            # warm-up pass: the classifier
+            nv.pwrite(fd, b"\x00" * page, p * page)
+        nv.flush()                          # flips, its entries drain
+        tf = tier.open("/hot.dat")
+        nvmm0, backend0 = nv.nvmm.stats_stored_bytes, tf.stats_bytes
+        for rnd in range(passes):
+            for p in range(n_pages):
+                nv.pwrite(fd, bytes([rnd + 1]) * page, p * page)
+        nv.flush()
+        assert nv.stats()["mode_migrations"] == (mode == "paged")
+        assert tf.snapshot() == bytes([passes]) * (page * n_pages)
+        persisted[mode] = (nv.nvmm.stats_stored_bytes - nvmm0
+                           + tf.stats_bytes - backend0)
+        nv.shutdown()
+    committed = passes * n_pages * page
+    assert persisted["log"] >= 2 * committed, persisted
+    assert 1.5 * persisted["paged"] <= persisted["log"], persisted
+
+
 def test_paged_mode_appends_nothing_to_the_log():
     pol = make_policy(batch_min=10 ** 6, batch_max=10 ** 6)  # no drain
     nv = NVCache(pol, Tier(DRAM))

@@ -28,7 +28,7 @@ from repro.analysis import racecheck
 from repro.core import NVCache, Policy, recover
 from repro.core.log import LogFullTimeout, NVLog
 from repro.core.nvmm import NVMM
-from repro.obs import metrics
+from repro.obs import metrics, overhead
 from repro.obs.flight import (EV_COMMIT, EV_META_OP, EV_NAMES,
                               EV_ROUTE_EPOCH, FLIGHT_REC, FlightRecorder,
                               decode_ring)
@@ -421,3 +421,35 @@ def test_stats_keeps_legacy_keys_and_matches_registry():
     assert s["alloc_waits"] == m["log.alloc_wait_us"]["count"]
     assert m["flight.event_total"] > 0       # at least the attach record
     nv.shutdown()
+
+
+# ------------------------------------------------------------ overhead gate
+@pytest.mark.parametrize("overhead_pct, coverage, broken", [
+    (10.0, 0.9, 0),          # on both limits
+    (-5.0, 1.1, 0),
+    (10.5, 1.0, 1),          # instrumentation too dear
+    (3.0, 0.89, 1),          # spans miss wall-clock time
+    (3.0, 1.11, 1),          # spans count time twice
+    (12.0, 1.2, 2),
+])
+def test_overhead_gate_limits(overhead_pct, coverage, broken, monkeypatch):
+    """``repro.obs.overhead`` fails a report past either limit (10% median
+    overhead, span coverage in [0.9, 1.1]) and passes one inside them;
+    ``main`` turns a failure into a non-zero exit."""
+    report = {"overhead_pct": overhead_pct, "span_coverage_ratio": coverage}
+    assert len(overhead.check(report)) == broken
+    monkeypatch.setattr(overhead, "measure", lambda: report)
+    assert overhead.main() == (1 if broken else 0)
+
+
+def test_overhead_report_fills_its_keys():
+    """One tiny real run of the gate's measurement: every key is filled
+    from the engine's own spans and the loop's samples."""
+    report = overhead.measure(repeats=1, gate_mib=1 / 16, span_mib=1 / 16)
+    assert len(report["overhead_pct_pairs"]) == 1
+    assert report["overhead_pct"] == report["overhead_pct_pairs"][0]
+    assert report["samples_us_plain"][0] > 0
+    assert report["samples_us_obs2"][0] > 0
+    assert 0 < report["foreground_span_s"]
+    assert report["span_coverage_ratio"] == pytest.approx(
+        report["foreground_span_s"] / report["wall_s"])
