@@ -3,12 +3,58 @@ and MLA (multi-head latent attention, MiniCPM3/DeepSeek-V2 style, with the
 absorbed decode path serving directly from the compressed latent cache)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_pallas
 from repro.models.common import ModelConfig
 from repro.models.layers import (apply_rope, blocked_attention, decode_attention,
                                  dense_init)
+from repro.parallel import context as pctx
+
+
+# ================================================================ dispatch
+
+def attention(q, k, v, *, causal, window=None, scale=None, block=1024,
+              q_offset=0, kv_len=None, recompute=False):
+    """Full-sequence attention, q: (B, Sq, H, D); k, v: (B, Skv, KV, D/Dv).
+
+    On the TPU, a call the flash kernel can take runs it: self-attention
+    over the whole sequence (Sq == Skv, no ``q_offset``, no ``kv_len``)
+    with ``window`` None or a static int.  Every other call, and every call
+    on the CPU, runs ``blocked_attention`` (``block``, ``recompute`` are
+    its own)."""
+    static_window = window is None or isinstance(window, int)
+    if (ops.on_tpu() and static_window and q.shape[1] == k.shape[1]
+            and isinstance(q_offset, int) and q_offset == 0 and kv_len is None):
+        return _flash(q, k, v, causal=causal, window=window, scale=scale)
+    return blocked_attention(q, k, v, causal=causal, window=window, block=block,
+                             q_offset=q_offset, kv_len=kv_len, scale=scale,
+                             recompute=recompute)
+
+
+def _flash(q, k, v, **kw):
+    """The flash kernel, per device under a bound mesh of several: a Mosaic
+    call is not partitioned automatically, so it runs in ``shard_map`` with
+    the batch over the data-parallel axes and the heads over ``model``
+    (each where it divides; GQA groups stay whole, since H and KV split
+    alike)."""
+    mesh = pctx.current_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention_pallas(q, k, v, **kw)
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    m = sizes.get("model", 1)
+    batch = dp if dp and q.shape[0] % math.prod(sizes[a] for a in dp) == 0 else None
+    heads = "model" if m > 1 and q.shape[2] % m == 0 and k.shape[2] % m == 0 else None
+    spec = P(batch, None, heads, None)
+    return jax.shard_map(lambda q, k, v: flash_attention_pallas(q, k, v, **kw),
+                         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
 
 
 # =============================================================== GQA / MQA
@@ -57,13 +103,11 @@ def gqa_forward(cfg: ModelConfig, p, x, rope=None, *, causal=True, window=None,
     # pin the head axis to the model mesh axis — without the constraint the
     # partitioner replicates the whole attention when it cannot propagate
     # sharding through the repeat/reshape (hillclimb 1, iteration 2)
-    from repro.parallel import context as pctx
     qa = pctx.constrain(qa, ("__dp__", None, "model", None))
     ka = pctx.constrain(ka, ("__dp__", None, "model", None))
     va = pctx.constrain(va, ("__dp__", None, "model", None))
-    o = blocked_attention(qa, ka, va, causal=causal, window=window,
-                          block=cfg.attn_block,
-                          scale=1.0 / (cfg.head_dim ** 0.5))
+    o = attention(qa, ka, va, causal=causal, window=window,
+                  block=cfg.attn_block, scale=1.0 / (cfg.head_dim ** 0.5))
     o = o[:, :, :H, :]
     out = o.reshape(x.shape[0], x.shape[1], -1) @ p["wo"].astype(x.dtype)
     if return_kv:
@@ -150,7 +194,6 @@ def mla_forward(cfg: ModelConfig, p, x, rope=None, *, causal=True,
                                                   (B, S, H, rd))], -1)
     # TP-friendliness (same reasoning as gqa_forward): pad the head axis to
     # the model-axis multiple and pin it, else MLA attention replicates
-    from repro.parallel import context as pctx
     if cfg.pad_heads_to and H % cfg.pad_heads_to:
         Hp = -(-H // cfg.pad_heads_to) * cfg.pad_heads_to
         pad = ((0, 0), (0, 0), (0, Hp - H), (0, 0))
@@ -158,9 +201,9 @@ def mla_forward(cfg: ModelConfig, p, x, rope=None, *, causal=True,
     q = pctx.constrain(q, ("__dp__", None, "model", None))
     k = pctx.constrain(k, ("__dp__", None, "model", None))
     v = pctx.constrain(v, ("__dp__", None, "model", None))
-    o = blocked_attention(q, k, v, causal=causal, block=cfg.attn_block,
-                          scale=1.0 / ((nd + rd) ** 0.5),
-                          recompute=cfg.attn_recompute)[:, :, :H, :]
+    o = attention(q, k, v, causal=causal, block=cfg.attn_block,
+                  scale=1.0 / ((nd + rd) ** 0.5),
+                  recompute=cfg.attn_recompute)[:, :, :H, :]
     out = o.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
     if return_kv:
         return out, (ckv, k_rope)

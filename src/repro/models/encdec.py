@@ -12,8 +12,7 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn
 from repro.models.common import ModelConfig
-from repro.models.layers import (blocked_attention, decode_attention,
-                                 dense_init, layernorm, swiglu)
+from repro.models.layers import decode_attention, dense_init, layernorm
 
 
 def sinusoid_pos(S, d):
@@ -101,8 +100,7 @@ def _dec_block(cfg, pl, x, enc_kv, *, self_kv=None, return_kv=False):
     B, S, _ = h.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (h @ pl["cross"]["wq"].astype(h.dtype)).reshape(B, S, H, hd)
-    o = blocked_attention(q, enc_kv[0], enc_kv[1], causal=False,
-                          block=cfg.attn_block)
+    o = attn.attention(q, enc_kv[0], enc_kv[1], causal=False, block=cfg.attn_block)
     x = x + o.reshape(B, S, -1) @ pl["cross"]["wo"].astype(h.dtype)
     x = x + _mlp(pl["mlp"], _ln(pl["ln3"], x, cfg.norm_eps))
     return x, kv

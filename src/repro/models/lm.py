@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention as flash
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -107,15 +108,16 @@ def moe_counters(cfg: ModelConfig, stats) -> dict:
             "moe_dropped": jnp.sum(stats["dropped"]).astype(jnp.int32)}
 
 
-def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
-    """Per-layer attention window (NEG_WINDOW_OFF = full attention)."""
-    if cfg.family == "hybrid" and cfg.swa_window:
+def layer_windows(cfg: ModelConfig):
+    """The layers' attention window.  One static value where every layer
+    has the same (an int, or None for full attention), so that attention
+    may take the flash kernel; a per-layer array only for a hybrid stack
+    that mixes windowed and global layers (NEG_WINDOW_OFF = full
+    attention)."""
+    if cfg.family == "hybrid" and cfg.swa_window and cfg.global_layers:
         win = jnp.full((cfg.n_layers,), cfg.swa_window, jnp.int32)
-        if cfg.global_layers:
-            win = win.at[jnp.array(cfg.global_layers)].set(NEG_WINDOW_OFF)
-        return win
-    w = cfg.swa_window if cfg.swa_window else NEG_WINDOW_OFF
-    return jnp.full((cfg.n_layers,), w, jnp.int32)
+        return win.at[jnp.array(cfg.global_layers)].set(NEG_WINDOW_OFF)
+    return cfg.swa_window or None
 
 
 # -------------------------------------------------------------------- block
@@ -175,11 +177,17 @@ def _moe(cfg: ModelConfig, p, h, bias):
 
 
 def _remat(cfg, fn):
+    """Remat "dots" saves the matmuls' outputs and the flash kernel's
+    residuals (its output and log-sum-exp), so that the backward runs no
+    forward of attention again."""
     if cfg.remat == "none":
         return fn
     if cfg.remat == "full":
         return jax.checkpoint(fn)
-    return jax.checkpoint(fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    policies = jax.checkpoint_policies
+    return jax.checkpoint(fn, policy=policies.save_from_both_policies(
+        policies.dots_with_no_batch_dims_saveable,
+        policies.save_only_these_names(*flash.RESIDUALS)))
 
 
 # ----------------------------------------------------------------- forward
@@ -207,8 +215,24 @@ def _segments(cfg: ModelConfig, params, buffers):
     if "dense_layers" not in params:
         return [(params["layers"], windows, bias)]
     n = cfg.first_dense
-    return [(params["dense_layers"], windows[:n], None),
-            (params["layers"], windows[n:], bias)]
+    per_layer = isinstance(windows, jax.Array)
+    return [(params["dense_layers"], windows[:n] if per_layer else windows, None),
+            (params["layers"], windows[n:] if per_layer else windows, bias)]
+
+
+def _scan_stack(cfg: ModelConfig, body, x, layers, windows, bias, *caches,
+                remat=False):
+    """``lax.scan`` of ``body(carry, pl, window, bias, *caches)`` over a stack
+    of layers.  A per-layer window array is scanned with the layers; a
+    static window reaches every layer as it is, and stays static."""
+    per_layer = isinstance(windows, jax.Array)
+
+    def step(carry, xs):
+        pl, win, b, *c = xs
+        return body(carry, pl, win if per_layer else windows, b, *c)
+
+    return jax.lax.scan(_remat(cfg, step) if remat else step, x,
+                        (layers, windows if per_layer else None, bias, *caches))
 
 
 def forward(cfg: ModelConfig, params, tokens, positions=None, buffers=None):
@@ -230,13 +254,12 @@ def _forward(cfg: ModelConfig, params, tokens, positions, buffers):
         x = x + params["pos_table"][:S][None].astype(x.dtype)
     rope = _rope_for(cfg, positions)
 
-    def body(carry, xs):
-        pl, win, bias = xs
+    def body(carry, pl, win, bias):
         y, stats, _ = _block(cfg, pl, carry, rope, win, bias)
         return y, stats
 
     for layers, windows, bias in _segments(cfg, params, buffers):
-        x, stats = jax.lax.scan(_remat(cfg, body), x, (layers, windows, bias))
+        x, stats = _scan_stack(cfg, body, x, layers, windows, bias, remat=True)
     with jax.named_scope("loss"):       # the head exists for the loss
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         un = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
@@ -298,14 +321,13 @@ def prefill(cfg: ModelConfig, params, tokens, max_len: int, positions=None,
         x = x + params["pos_table"][:S][None].astype(x.dtype)
     rope = _rope_for(cfg, positions)
 
-    def body(carry, xs):
-        pl, win, bias = xs
+    def body(carry, pl, win, bias):
         y, _stats, kv = _block(cfg, pl, carry, rope, win, bias, return_kv=True)
         return y, kv
 
     parts = []
     for layers, windows, bias in _segments(cfg, params, buffers):
-        x, kvs = jax.lax.scan(body, x, (layers, windows, bias))
+        x, kvs = _scan_stack(cfg, body, x, layers, windows, bias)
         parts.append(kvs)
     if len(parts) > 1:                  # caches stack all layers in order
         kvs = jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
@@ -397,19 +419,16 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, buffers=None):
     rope = _rope_for(cfg, positions)
     keys = _cache_keys(cfg)
 
-    def body(carry, xs):
-        pl, win, bias = xs[:3]
-        caches = xs[3:]
-        y, new = _block_decode(cfg, pl, carry, rope, win, caches, pos, bias)
-        return y, new
+    def body(carry, pl, win, bias, *caches):
+        return _block_decode(cfg, pl, carry, rope, win, caches, pos, bias)
 
     segments = _segments(cfg, params, buffers)
     parts, start = [], 0
     for layers, windows, bias in segments:
-        n = windows.shape[0]
+        n = jax.tree.leaves(layers)[0].shape[0]
         caches = [cache[k] if len(segments) == 1 else cache[k][start:start + n]
                   for k in keys]
-        x, new = jax.lax.scan(body, x, (layers, windows, bias, *caches))
+        x, new = _scan_stack(cfg, body, x, layers, windows, bias, *caches)
         parts.append(new)
         start += n
     for i, k in enumerate(keys):
